@@ -293,10 +293,10 @@ def bench_sweep(n: int = 400, m: int = 5000, max_q: int = 256,
         rec["delete"]["engines"]["loop_segment"]["sweep_us"]
         / rec["delete"]["engines"]["fused"]["sweep_us"], 2)
 
-    # VMEM-resident Pallas delete column (kernels/bdeu_sweep.delete_scores:
+    # VMEM-resident Pallas delete column (kernels/bdeu_sweep.delete_marginals:
     # the one family table accumulates in VMEM scratch, every parent-slot
-    # marginal is reduced to its BDeu score in-kernel, only the (n,) column
-    # is written) vs the two-step path it replaced — bdeu_count Pallas table
+    # marginal is formed in VMEM and the (S + 1) tables are written, then
+    # reduced by jnp) vs the two-step path it replaced — bdeu_count Pallas table
     # build, then jnp marginalization — which round-trips the (max_q, r_max)
     # table through HBM once per column.  Interpret-mode wall time measures
     # correctness-path cost; the HBM-byte accounting (analytic, logical f32
@@ -321,7 +321,9 @@ def bench_sweep(n: int = 400, m: int = 5000, max_q: int = 256,
                       + n * table                  # broadcast read, n ways
                       + 2 * n * table              # marginal slab write+read
                       + 4 * n)                     # column write
-    vmem_bytes = inputs + 4 * n                    # table/marginals stay VMEM
+    n_slots = max(1, min(n, max_q.bit_length() - 1))
+    vmem_bytes = (inputs + 2 * (n_slots + 1) * table  # slot tables write+read
+                  + 4 * n)                            # column write
     rec["delete_pallas"] = {
         "vmem_resident_us": vmem_us,
         "two_step_us": round(two_step_us, 1),

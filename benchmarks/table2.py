@@ -43,6 +43,7 @@ import numpy as np
 from repro.core import GESConfig, ScoreCache, cges, fges_host, ges_host
 from repro.core.dag import smhd_np
 from repro.data.bn import benchmark_bn, forward_sample
+from repro.launch.compile_cache import enable_compile_cache
 
 ALGOS = ["fGES", "GES", "cGES-2", "cGES-4", "cGES-8",
          "cGES-L-2", "cGES-L-4", "cGES-L-8"]
@@ -135,6 +136,7 @@ def main():
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     rows = bench(args.families, args.scale, args.m, list(range(args.seeds)),
                  data_shards=args.data_shards)
     summary = summarize(rows)

@@ -63,17 +63,19 @@ ring program, ``fusion.fuse_trace``, ``score_cache.lookup_or_compute``:
 **Pass 3 — VMEM budgets** (:mod:`repro.analysis.vmem`)
 
 Symbolic per-kernel VMEM footprints from the same tile/grid parameters the
-kernels take, gated against a ~16 MiB/core TPU budget — so a config that
-would only fail at TPU compile time at paper scale fails here first.
-Repo-default paper-scale table (max_q=4096, compiled r_pad=128,
-munin-scale k_pad=1152; ``V001`` on overflow):
+kernels take, gated against the scoped VMEM limit each kernel runs under
+(64 MiB for the BDeu kernels, the compiler's 16 MiB default otherwise) — so
+a config that would only fail at TPU compile time at paper scale fails here
+first.  Block alignment is not modelled; tests/test_tpu_compile.py compiles
+the BDeu kernels for a described v5e chip.  Repo-default paper-scale table
+(max_q=1024, r_max=5, tile_m=512; ``V001`` on overflow):
 
 ==================  ==========  ====================================
 kernel              footprint   dominant term
 ==================  ==========  ====================================
-bdeu_count           6.13 MiB   (tile_m, max_q) one-hot slab
-bdeu_sweep          12.32 MiB   (max_q, tile_n*r_max) counts block x2
-bdeu_delete         12.26 MiB   one-hots + (max_q, r_pad) table x2
+bdeu_count           1.05 MiB   (q_pad, tile_m) bf16 one-hot slab
+bdeu_sweep           6.63 MiB   (r_max, q_pad, 128) counts block x2
+bdeu_delete          2.46 MiB   (q_pad, tile_m) bf16 one-hot slab
 flash_attention      0.81 MiB   (BQ, BK) logits/probs pair
 ssd_scan             0.66 MiB   (Q, Q) decay mask
 ==================  ==========  ====================================

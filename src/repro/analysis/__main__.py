@@ -35,7 +35,8 @@ def main(argv=None) -> int:
                    help="contracts: skip the (slower) steady-state "
                         "re-trace execution pin, keep the trace checks")
     p.add_argument("--vmem-budget", type=int, default=None,
-                   help="VMEM budget in bytes (default: 16 MiB/core TPU)")
+                   help="VMEM budget in bytes for every kernel (default: "
+                        "each kernel's own scoped VMEM limit)")
     p.add_argument("--rules", default=None,
                    help="comma-separated lint rule subset (e.g. R001,R004)")
     args = p.parse_args(argv)
@@ -76,9 +77,8 @@ def main(argv=None) -> int:
         report.info["contracts"] = info
 
     if not args.skip_vmem:
-        from .vmem import DEFAULT_BUDGET, run_vmem_checks
-        budget = args.vmem_budget or DEFAULT_BUDGET
-        findings, info = run_vmem_checks(budget)
+        from .vmem import run_vmem_checks
+        findings, info = run_vmem_checks(args.vmem_budget)
         report.extend(findings)
         report.passes_run.append("vmem")
         report.info["vmem"] = info

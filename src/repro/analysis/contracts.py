@@ -53,7 +53,7 @@ FORBIDDEN_DTYPES = ("float64", "complex128")
 def _sub_jaxprs(params: dict):
     """Every Jaxpr/ClosedJaxpr nested in an eqn's params (pjit bodies,
     while cond/body, cond branches, scan, shard_map, custom_* calls)."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
     out = []
 
     def visit(v):
@@ -72,7 +72,7 @@ def _sub_jaxprs(params: dict):
 
 def iter_eqns(jaxpr):
     """Yield every eqn in ``jaxpr`` and (recursively) its sub-jaxprs."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
     if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
@@ -204,8 +204,7 @@ def run_contract_checks(backends: Tuple[str, ...] = ("segment", "fused",
     from ..core import bdeu, fusion, score_cache
     from ..core.ges import GESConfig, ges_jit
     from ..core.ring import RingSpec, build_ring_program, ring_cges
-    from ..core.sweeps import (shard_map_compat, sweep_column_body,
-                               sweep_matrix_body,
+    from ..core.sweeps import (sweep_column_body, sweep_matrix_body,
                                sweep_matrix_restricted_body)
     from ..core.partition import pid_table_from_allowed
 
@@ -240,7 +239,8 @@ def run_contract_checks(backends: Tuple[str, ...] = ("segment", "fused",
     mesh = Mesh(np.array(jax.devices()[:1]), (axis,))
 
     def psum_count_of(fn, *args):
-        mapped = shard_map_compat(fn, mesh, (P(axis, None),), P())
+        mapped = jax.shard_map(fn, mesh=mesh, in_specs=(P(axis, None),),
+                               out_specs=P(), check_vma=False)
         return jax.make_jaxpr(mapped)(*args), None
 
     count_paths = {}

@@ -1,11 +1,12 @@
 """Pass 3 — symbolic VMEM budgets for the Pallas kernels.
 
-A TPU core has ~16 MiB of VMEM.  Every Pallas kernel in this repo keeps an
-accumulator (or running state) resident in VMEM across a sequential grid,
-plus per-step input blocks and in-kernel one-hot/softmax temporaries — and
-nothing checks that a (tile, max_q, r_pad) configuration actually fits
-until the TPU compiler rejects it at paper scale (n = 1041 / max_q = 4096
-is exactly where it gets tight).  This pass computes the footprint
+A Pallas kernel may use the TPU compiler's scoped VMEM limit: 16 MiB by
+default, raised by the BDeu kernels to 64 MiB (a v5e core has 128 MiB).
+Every Pallas kernel in this repo keeps an accumulator (or running state)
+resident in VMEM across a sequential grid, plus per-step input blocks and
+in-kernel one-hot/softmax temporaries — and nothing checks that a (tile,
+max_q, r_max) configuration actually fits until the TPU compiler rejects it
+at paper scale.  This pass computes the footprint
 symbolically from the same parameters the kernels take, so an over-budget
 configuration fails at analysis time, with a per-term breakdown instead of
 a compiler error.
@@ -20,9 +21,11 @@ Model (documented heuristic, deliberately conservative):
   pair, the scatter-by-matmul chunk) count once each — these are the terms
   that actually dominate (a (256, 4096) one-hot is 4 MiB).
 
-The four kernels and their repo-default paper-scale configurations are
-tabulated in ``DEFAULT_CONFIGS`` (tile defaults from the ops wrappers;
-max_q / r_pad / k_pad at the GESConfig defaults and munin-scale n).
+The kernels and their repo-default paper-scale configurations are
+tabulated in ``DEFAULT_CONFIGS`` (tile defaults from the ops wrappers,
+max_q at the entry points' 1024 and r_max at munin's 5).  The footprint models
+do not check (8, 128) block alignment; tests/test_tpu_compile.py compiles
+the kernels for a described v5e chip for that.
 """
 from __future__ import annotations
 
@@ -30,12 +33,12 @@ import dataclasses
 import math
 from typing import Callable, Dict, List, Optional, Tuple
 
+from ..kernels.common import VMEM_LIMIT_BYTES
 from .findings import Finding
 
 MIB = 2 ** 20
-# Per-core VMEM by platform.  v4/v5e/v5p are all ~16 MiB-class; "tpu" is
-# the default gate.  A deliberately generous "interpret" budget exists so
-# CPU-interpret runs (which have no real VMEM) can still exercise the gate.
+# The compiler's default scoped VMEM limit per core, by platform — the gate
+# for kernels that do not raise it (see KERNEL_BUDGETS).
 VMEM_BUDGETS: Dict[str, int] = {
     "tpu": 16 * MIB,
     "tpu_v4": 16 * MIB,
@@ -46,6 +49,7 @@ DEFAULT_BUDGET = VMEM_BUDGETS["tpu"]
 
 F32 = 4
 I32 = 4
+BF16 = 2
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -101,58 +105,57 @@ class Footprint:
 # Per-kernel symbolic footprints (mirror the kernels' BlockSpecs/scratch)
 # ---------------------------------------------------------------------------
 
-def bdeu_count_footprint(*, max_q: int = 4096, r_pad: int = 128,
-                         tile_m: int = 256) -> Footprint:
-    """kernels/bdeu_count: one-hot contraction, (max_q, r_pad) accumulator
-    revisited across the sequential m grid."""
-    return Footprint("bdeu_count", dict(max_q=max_q, r_pad=r_pad,
+def bdeu_count_footprint(*, max_q: int = 1024, r_max: int = 5,
+                         tile_m: int = 512) -> Footprint:
+    """kernels/bdeu_count: transposed one-hot contraction, (r_pad, q_pad)
+    accumulator revisited across the sequential m grid."""
+    q_pad, r_pad = _round_up(max_q, 128), _round_up(r_max, 8)
+    return Footprint("bdeu_count", dict(max_q=max_q, r_max=r_max,
                                         tile_m=tile_m), [
-        Term("in:cfg", (tile_m,), I32, buffers=2),
-        Term("in:child", (tile_m,), I32, buffers=2),
-        Term("out:counts", (max_q, r_pad), F32),          # constant index map
-        Term("tmp:oh_cfg", (tile_m, max_q), F32),
-        Term("tmp:oh_child", (tile_m, r_pad), F32),
+        Term("in:cfg", (1, tile_m), I32, buffers=2),
+        Term("in:child", (1, tile_m), I32, buffers=2),
+        Term("out:counts", (r_pad, q_pad), F32),          # constant index map
+        Term("tmp:oh_cfg", (q_pad, tile_m), BF16),
+        Term("tmp:oh_child", (r_pad, tile_m), BF16),
     ])
 
 
-def bdeu_sweep_footprint(*, max_q: int = 4096, r_max: int = 8,
-                         tile_m: int = 256, tile_n: int = 32) -> Footprint:
-    """kernels/bdeu_sweep.sweep_counts: joint child-value-batched insert
-    sweep; the (max_q, tile_n * r_max) accumulator block rides the (b, c)
-    grid axes (double-buffered), revisited across m innermost."""
+def bdeu_sweep_footprint(*, max_q: int = 1024, r_max: int = 5,
+                         tile_m: int = 512) -> Footprint:
+    """kernels/bdeu_sweep.sweep_counts: joint insert sweep; the
+    (r_max, q_pad, 128) output block rides the (b, c) grid axes
+    (double-buffered), revisited across m innermost."""
+    q_pad = _round_up(max_q, 8)
     return Footprint("bdeu_sweep", dict(max_q=max_q, r_max=r_max,
-                                        tile_m=tile_m, tile_n=tile_n), [
-        Term("in:cfg", (tile_m,), I32, buffers=2),
-        Term("in:child", (tile_m,), I32, buffers=2),
-        Term("in:data", (tile_m, tile_n), I32, buffers=2),
-        Term("out:counts", (max_q, tile_n * r_max), F32, buffers=2),
-        Term("tmp:oh_cfg", (tile_m, max_q), F32),
-        Term("tmp:oh_all", (tile_m, tile_n * r_max), F32),
+                                        tile_m=tile_m), [
+        Term("in:cfg", (1, tile_m), I32, buffers=2),
+        Term("in:child", (1, tile_m), I32, buffers=2),
+        Term("in:data", (tile_m, 128), I32, buffers=2),
+        Term("out:counts", (r_max, q_pad, 128), F32, buffers=2),
+        Term("tmp:oh_cfg", (q_pad, tile_m), BF16),
+        Term("tmp:data_eq_a", (tile_m, 128), BF16),
     ])
 
 
-def bdeu_delete_footprint(*, max_q: int = 4096, r_pad: int = 128,
-                          tile_m: int = 256, k_pad: int = 1152,
-                          n_slots: int = 11,
-                          chunk_q: Optional[int] = None) -> Footprint:
-    """kernels/bdeu_sweep.delete_scores: VMEM-resident family table +
-    in-VMEM scatter-by-matmul marginalization (PR 5).  ``chunk_q`` defaults
-    to the kernel's own min(max_q, 256) bound; k_pad = round_up(n | W, 128);
-    n_slots <= floor(log2(max_q))."""
-    cq = min(max_q, 256) if chunk_q is None else chunk_q
-    return Footprint("bdeu_delete", dict(max_q=max_q, r_pad=r_pad,
-                                         tile_m=tile_m, k_pad=k_pad,
-                                         n_slots=n_slots, chunk_q=cq), [
-        Term("in:cfg", (tile_m,), I32, buffers=2),
-        Term("in:child", (tile_m,), I32, buffers=2),
-        Term("in:cand+slots", (k_pad + 3 * n_slots + 2,), I32),
-        Term("out:scores", (k_pad,), F32),
-        Term("scratch:family_table", (max_q, r_pad), F32),
-        Term("tmp:oh_cfg", (tile_m, max_q), F32),
-        Term("tmp:oh_child", (tile_m, r_pad), F32),
-        Term("tmp:scatter_onehot", (cq, max_q), F32),
-        Term("tmp:marginal_acc", (max_q, r_pad), F32),
-        Term("tmp:chunk_rows", (cq, r_pad), F32),
+def bdeu_delete_footprint(*, max_q: int = 1024, r_max: int = 5,
+                          tile_m: int = 512, n_slots: int = 10,
+                          chunk_q: int = 256) -> Footprint:
+    """kernels/bdeu_sweep.delete_marginals: VMEM-resident family table +
+    in-VMEM scatter-by-matmul marginalization into the (S + 1) output
+    tables; n_slots <= floor(log2(max_q))."""
+    q_pad, r_pad = _round_up(max_q, 128), _round_up(r_max, 8)
+    return Footprint("bdeu_delete", dict(max_q=max_q, r_max=r_max,
+                                         tile_m=tile_m, n_slots=n_slots,
+                                         chunk_q=chunk_q), [
+        Term("in:cfg", (1, tile_m), I32, buffers=2),
+        Term("in:child", (1, tile_m), I32, buffers=2),
+        Term("in:slot_maps", (n_slots, q_pad), I32),
+        Term("out:marginals", (n_slots + 1, r_pad, q_pad), F32),
+        Term("scratch:family_table", (r_pad, q_pad), F32),
+        Term("tmp:oh_cfg", (q_pad, tile_m), BF16),
+        Term("tmp:oh_child", (r_pad, tile_m), BF16),
+        Term("tmp:scatter_onehot", (q_pad, chunk_q), F32),
+        Term("tmp:marginal_acc", (r_pad, q_pad), F32),
     ])
 
 
@@ -202,17 +205,28 @@ KERNEL_FOOTPRINTS: Dict[str, Callable[..., Footprint]] = {
     "ssd_scan": ssd_scan_footprint,
 }
 
-# Paper-scale representative configurations: GESConfig.max_q = 4096, the
-# compiled r_pad = round_up(r_max, 128) = 128, munin-scale candidate column
-# k_pad = round_up(1041, 128) = 1152, tiles at the ops-wrapper defaults.
+# Paper-scale representative configurations: max_q = 1024 (the entry
+# points' table bound), r_max = 5 (munin's largest arity), tiles at the
+# ops-wrapper defaults (kernels/common.py), n_slots = floor(log2(max_q)).
 DEFAULT_CONFIGS: Dict[str, Dict[str, int]] = {
-    "bdeu_count": dict(max_q=4096, r_pad=128, tile_m=256),
-    "bdeu_sweep": dict(max_q=4096, r_max=8, tile_m=256, tile_n=32),
-    "bdeu_delete": dict(max_q=4096, r_pad=128, tile_m=256,
-                        k_pad=_round_up(1041, 128), n_slots=11),
+    "bdeu_count": dict(max_q=1024, r_max=5, tile_m=512),
+    "bdeu_sweep": dict(max_q=1024, r_max=5, tile_m=512),
+    "bdeu_delete": dict(max_q=1024, r_max=5, tile_m=512, n_slots=10),
     "flash_attention": dict(block_q=128, block_k=128, head_dim=128),
     "ssd_scan": dict(chunk=128, head_dim_p=64, state_n=128),
 }
+
+# The BDeu kernels raise the compiler's scoped VMEM limit to
+# VMEM_LIMIT_BYTES; the others run under its 16 MiB default.
+KERNEL_BUDGETS: Dict[str, int] = {
+    "bdeu_count": VMEM_LIMIT_BYTES,
+    "bdeu_sweep": VMEM_LIMIT_BYTES,
+    "bdeu_delete": VMEM_LIMIT_BYTES,
+}
+
+
+def kernel_budget(kernel: str) -> int:
+    return KERNEL_BUDGETS.get(kernel, DEFAULT_BUDGET)
 
 
 def footprint(kernel: str, **params) -> Footprint:
@@ -222,15 +236,18 @@ def footprint(kernel: str, **params) -> Footprint:
     return KERNEL_FOOTPRINTS[kernel](**params)
 
 
-def check_config(kernel: str, budget: int = DEFAULT_BUDGET,
+def check_config(kernel: str, budget: Optional[int] = None,
                  **params) -> Optional[Finding]:
-    """Budget-gate one kernel configuration; None when it fits."""
-    return footprint(kernel, **params).check(budget)
+    """Budget-gate one kernel configuration (against its own VMEM limit
+    unless ``budget`` is given); None when it fits."""
+    return footprint(kernel, **params).check(
+        kernel_budget(kernel) if budget is None else budget)
 
 
-def run_vmem_checks(budget: int = DEFAULT_BUDGET,
+def run_vmem_checks(budget: Optional[int] = None,
                     configs: Optional[Dict[str, Dict[str, int]]] = None):
-    """Footprint every kernel at its (default or given) configuration.
+    """Footprint every kernel at its (default or given) configuration,
+    against its own VMEM limit unless ``budget`` is given.
 
     Returns ``(findings, info)`` — info carries the full per-term breakdown
     for the JSON report (the budget table in the package docstring is
@@ -241,7 +258,7 @@ def run_vmem_checks(budget: int = DEFAULT_BUDGET,
     for kernel, params in configs.items():
         fp = footprint(kernel, **params)
         info["kernels"][kernel] = fp.to_dict()
-        bad = fp.check(budget)
+        bad = fp.check(kernel_budget(kernel) if budget is None else budget)
         if bad is not None:
             findings.append(bad)
     return findings, info

@@ -29,10 +29,10 @@ The device engine's candidate sweeps additionally have a **fused** mode
   *marginalization* of the ONE current-family (q0, r) table over parent slot
   x — :func:`fused_delete_scores` builds that table once and reads the whole
   delete column off it with zero re-counting (n table builds -> 1).  Under
-  ``"fused_pallas"`` the build, the per-slot marginalizations and the BDeu
-  reductions all happen inside ONE VMEM-resident Pallas kernel
-  (``kernels/bdeu_sweep.delete_scores``), so the table never round-trips
-  through HBM and only the (n,)/(W,) score column is written back.
+  ``"fused_pallas"`` the build and the per-slot marginalizations happen
+  inside ONE VMEM-resident Pallas kernel
+  (``kernels/bdeu_sweep.delete_marginals``), which writes one table per
+  parent slot; the BDeu reduction is the same jnp code as ``"fused"``.
 
 The unified caller-facing layer over these primitives is ``repro.core.sweeps``.
 
@@ -296,19 +296,21 @@ def single_impl(counts_impl: str) -> str:
 
 
 def _onehot_all(data: Array, r_max: int) -> Array:
-    """(m, n*r_max) padded one-hot of every data column — child-independent,
-    so full sweeps hoist it out of the per-child map."""
+    """(m, r_max*n) value-major padded one-hot of every data column (column
+    a*n + x is [X_x == a]) — child-independent, so full sweeps hoist it out
+    of the per-child map."""
     m, n = data.shape
-    return jax.nn.one_hot(data, r_max, dtype=jnp.float32).reshape(m, n * r_max)
+    oh = jax.nn.one_hot(data, r_max, dtype=jnp.float32)            # (m, n, r)
+    return oh.transpose(0, 2, 1).reshape(m, r_max * n)
 
 
 def _sweep_counts_segment(cfg0: Array, child_col: Array, oh_all: Array,
                           max_q: int, r_max: int) -> Array:
-    """Joint sweep counts (r_max, max_q, n*r_max) via one segment-sum.
+    """Joint sweep counts (r_max, r_max, max_q, n) via one segment-sum.
 
-    counts[b, j0, x*r_max + a] = #(child=b, cfg0=j0, X_x=a).  The jnp
-    reference for the bdeu_sweep Pallas kernel; ``oh_all`` is the
-    (m, n*r_max) data one-hot from :func:`_onehot_all`.
+    counts[b, a, j0, x] = #(child=b, X_x=a, cfg0=j0) — the layout of the
+    bdeu_sweep Pallas kernel, which this jnp path mirrors; ``oh_all`` is the
+    value-major (m, r_max*n) data one-hot from :func:`_onehot_all`.
 
     Sentinel rows (child = r_max, from the data-axis sharder's ragged-m
     padding) are routed to an explicit overflow segment and sliced off —
@@ -319,7 +321,8 @@ def _sweep_counts_segment(cfg0: Array, child_col: Array, oh_all: Array,
                     r_max * max_q)
     counts = jax.ops.segment_sum(
         oh_all, idx, num_segments=r_max * max_q + 1)[: r_max * max_q]
-    return counts.reshape(r_max, max_q, oh_all.shape[1])
+    n = oh_all.shape[1] // r_max
+    return counts.reshape(r_max, max_q, r_max, n).transpose(0, 2, 1, 3)
 
 
 def fused_insert_scores(
@@ -382,16 +385,16 @@ def fused_insert_scores(
                                              data_axis_name=data_axis_name)
     else:
         if oh_all is not None and pids is not None:
-            cols = (pids[:, None] * r_max
-                    + jnp.arange(r_max, dtype=pids.dtype)[None, :]).reshape(-1)
+            n = data.shape[1]
+            cols = (jnp.arange(r_max, dtype=pids.dtype)[:, None] * n
+                    + pids[None, :]).reshape(-1)
             oh_all = jnp.take(oh_all, cols, axis=1)
         elif oh_all is None:
             oh_all = _onehot_all(data_c, r_max)
         counts = _sweep_counts_segment(cfg0c, child_col, oh_all, max_q, r_max)
         counts = _psum_counts(counts, data_axis_name)
-    # (b, j0, x, a) -> per-candidate tables (x, (j0, a), b)
-    c4 = counts.reshape(r_max, max_q, w, r_max)
-    slab = c4.transpose(2, 1, 3, 0).reshape(w, max_q * r_max, r_max)
+    # (b, a, j0, x) -> per-candidate tables (x, (j0, a), b)
+    slab = counts.transpose(3, 2, 1, 0).reshape(w, max_q * r_max, r_max)
     q = q0.astype(jnp.float32) * ar_c.astype(jnp.float32)         # (w,)
     scores = _bdeu_from_counts(slab, q, arities[child], ess)
     log_q0 = jnp.sum(jnp.where(parent_mask,
@@ -444,25 +447,25 @@ def fused_delete_scores(
     ``pids``: optional (W,) candidate subset (ring E_i) — only the W
     marginalization maps are built and the return shape is (W,).
 
-    With ``counts_impl="fused_pallas"`` the whole two-step dance — table
-    build, HBM round-trip, jnp marginalization — collapses into ONE Pallas
-    kernel (``kernels/bdeu_sweep.delete_scores``): the family table is
-    accumulated in VMEM and each parent slot's marginal is reduced to its
-    BDeu score in-register, so only the (n,)/(W,) score column ever reaches
-    HBM.  Since a family has at most ``floor(log2(max_q))`` real (arity > 1)
-    parents before it overflows the table bound (each multiplies q0 by at
-    least 2), the kernel marginalizes that many slots; candidates that are
-    not real parents read the base-family score off slot 0 (the identity
-    marginalization, exactly this function's jnp no-op convention), and
-    overflow-guarded families (q0 > max_q) only need the +/-inf *pattern*
-    below, which the shared guard supplies.
+    With ``counts_impl="fused_pallas"`` the table build and the
+    marginalizations collapse into ONE Pallas kernel
+    (``kernels/bdeu_sweep.delete_marginals``): the family table is
+    accumulated in VMEM, each parent slot's marginal is formed there, and
+    only the (S + 1) slot tables reach HBM — the w-wide marginal slab of the
+    jnp path is never built.  Since a family has at most
+    ``floor(log2(max_q))`` real (arity > 1) parents before it overflows the
+    table bound (each multiplies q0 by at least 2), the kernel marginalizes
+    that many slots; candidates that are not real parents read the
+    base-family score off slot 0 (the identity marginalization, exactly this
+    function's jnp no-op convention), and overflow-guarded families
+    (q0 > max_q) only need the +/-inf *pattern* below, which the shared
+    guard supplies.  Both paths reduce tables to scores with the same
+    :func:`_bdeu_from_counts`, so identical tables score identically.
 
-    ``data_axis_name``: instance axis sharded over that mesh axis.  The VMEM
-    kernel reduces counts to *scores* in-register and scores are not additive
-    over shards, so under data sharding ``"fused_pallas"`` routes to the
-    two-step path (table build via the psum-able ``contingency_counts``
-    wrapper + jnp marginalization) — the kernel's own per-shard accumulation
-    stays untouched.
+    ``data_axis_name``: instance axis sharded over that mesh axis.  The
+    kernel marginalizes the *local* family table, so under data sharding
+    ``"fused_pallas"`` routes to the two-step path (table build via the
+    psum-able ``contingency_counts`` wrapper + jnp marginalization).
     """
     n = data.shape[1]
     cfg0, q0 = _slot_encode(data, arities, parent_mask)
@@ -481,7 +484,7 @@ def fused_delete_scores(
     w = slot_ar.shape[0]
 
     if counts_impl == "fused_pallas" and data_axis_name is None:
-        from ..kernels.bdeu_sweep import delete_scores
+        from ..kernels.bdeu_sweep import delete_marginals
 
         n_slots = max(1, min(n, max(int(max_q).bit_length() - 1, 1)))
         real = parent_mask & (arities > 1)               # identity slots skip
@@ -499,12 +502,12 @@ def fused_delete_scores(
         ids_c = jnp.minimum(slot_ids, n - 1)
         ar_s = jnp.where(live, jnp.take(slot_ar_full, ids_c), 1)
         low_s = jnp.where(live, jnp.take(low_full, ids_c), 1)
-        qr = jnp.concatenate([
-            q0.astype(jnp.float32)[None],
-            (q0 // ar_s).astype(jnp.float32),
-            arities[child].astype(jnp.float32)[None]])
-        scores = delete_scores(cfg0c, child_col, cand_slot, ar_s, low_s, qr,
-                               ess=ess, max_q=max_q, r_max=r_max)
+        # slot 0 is the family table itself; slot s + 1 sums out parent s
+        tables = delete_marginals(cfg0c, child_col, ar_s, low_s,
+                                  max_q=max_q, r_max=r_max)
+        q_slots = jnp.concatenate([q0[None], q0 // ar_s])
+        slot_scores = _bdeu_from_counts(tables, q_slots, arities[child], ess)
+        scores = jnp.take(slot_scores, cand_slot)
     else:
         impl = single_impl(counts_impl)
         if impl == "onehot":
@@ -782,15 +785,12 @@ def _deltas_impl(data, arities, adj, ess, max_q, r_max, counts_impl,
     else:
         per_child = (per_child_delete_fused if counts_impl in FUSED_IMPLS
                      else per_child_delete)
-    if counts_impl == "fused" and child_chunk is None:
+    if counts_impl in FUSED_IMPLS and child_chunk is None:
         # A fused child sweep materializes a per-child slab — insert: the
-        # (r_max * max_q, n * r_max) joint counts; delete: the (n, max_q,
+        # (r_max, r_max, max_q, n) joint counts; delete: the (n, max_q,
         # r_max) marginalization stack.  Map children sequentially so that
         # slab exists for one child at a time instead of vmapping it n-wide
-        # (n^2-scale peak memory).  ("fused_pallas" is exempt: pallas_call
-        # in interpret mode cannot trace lax.map's zero-size remainder batch
-        # on jax 0.4.x — callers bound its memory with an explicit
-        # child_chunk.)
+        # (n^2-scale peak memory: gigabytes at paper scale).
         child_chunk = 1
 
     def base_for(ch, masks):

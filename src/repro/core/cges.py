@@ -79,6 +79,11 @@ class CGESResult:
     # config.family_cache was on (host engine: the shared DeviceFamilyCache;
     # jax engine: summed per-member cache counters); None otherwise.
     family_cache_stats: Optional[dict] = None
+    # lockstep engines: every member's graph and score in the last improving
+    # round — the (graphs, scores) the compiled ring returns; the fine-tune
+    # starts from the best of them.  None for engine="async".
+    ring_graphs: Optional[np.ndarray] = None
+    ring_graph_scores: Optional[np.ndarray] = None
 
 
 def edge_add_limit(n: int, k: int) -> int:
@@ -127,6 +132,7 @@ def cges(
     graphs = [np.zeros((n, n), dtype=np.int8) for _ in range(k)]
     best_score = -np.inf
     best_adj = np.zeros((n, n), dtype=np.int8)
+    best_graphs = best_graph_scores = None
     evals = 0
     ring_scores: List[float] = []
     # the paper's shared 'concurrent safe data structure': one score cache
@@ -224,14 +230,18 @@ def cges(
         if round_best > best_score + config.tol:
             best_score = round_best
             best_adj = graphs[int(np.argmax(new_scores))].copy()
+            best_graphs = np.stack(graphs)
+            best_graph_scores = np.asarray(new_scores)
             go = True
         else:
             go = False
 
-    return _finish_cges(
+    res = _finish_cges(
         data, arities, data_j, ar_j, r_max, best_adj,
         config, engine, cache, dev_cache, jax_caches, evals,
         rounds, ring_scores, edge_masks, parallel_wall, t0)
+    res.ring_graphs, res.ring_graph_scores = best_graphs, best_graph_scores
+    return res
 
 
 def _finish_cges(data, arities, data_j, ar_j, r_max, best_adj,

@@ -49,7 +49,7 @@ from . import bdeu, score_cache
 from .dag import closure_after_edge, transitive_closure, transitive_closure_np
 from .partition import pid_table_from_allowed
 from .sweeps import (DATA_AXIS, KIND_CODES, _data_mesh, pad_data_rows,
-                     shard_map_compat, sweep, sweep_column_body,
+                     sweep, sweep_column_body,
                      sweep_column_cached, sweep_matrix_body,
                      sweep_matrix_restricted_body)
 
@@ -116,6 +116,22 @@ class GESConfig:
 # one API, kind="insert"|"delete", optional pids restriction, engine-masked
 # columns identical under the loop and fused backends.
 # ---------------------------------------------------------------------------
+
+# Score-equivalent insertions.  When Pa_x == Pa_y, inserting x -> y and
+# y -> x give Markov-equivalent DAGs, so BDeu (score-equivalent) assigns them
+# EXACTLY equal deltas; computed in f32 they differ only by rounding, and the
+# rounding differs between count backends, XLA versions and devices.  Both
+# GES loops therefore let the argmax pick the pair by value and then fix its
+# direction by rule — the lower full-n flat index, min(x, y) -> max(x, y) —
+# whenever the reverse insertion is also legal.  Trajectories then agree
+# across engines instead of following rounding noise.
+
+def _canonical_insert_np(x: int, y: int, adj: np.ndarray,
+                         valid: np.ndarray):
+    if x > y and valid[y, x] and np.array_equal(adj[:, x], adj[:, y]):
+        return y, x
+    return x, y
+
 
 def _q_guard_np(adj: np.ndarray, arities: np.ndarray, max_q: int) -> np.ndarray:
     """Boolean (n, n) matrix: True where adding x->y keeps q_y <= max_q."""
@@ -354,6 +370,7 @@ def ges_host(
             x, y = np.unravel_index(np.argmax(masked), masked.shape)
             if not np.isfinite(masked[x, y]) or masked[x, y] <= cfg.tol:
                 break
+            x, y = _canonical_insert_np(int(x), int(y), adj, valid)
             adj[x, y] = 1
             reach = closure_after_edge(reach, int(x), int(y))
             n_ins += 1
@@ -388,6 +405,14 @@ def _masked_argmax(mat: Array):
     flat = mat.reshape(-1)
     idx = jnp.argmax(flat)
     return idx, flat[idx]
+
+
+def _canonical_insert(x, y, adj, reverse_ok):
+    """Traced twin of :func:`_canonical_insert_np`: swap to y -> x when that
+    has the lower flat index, is legal, and Pa_x == Pa_y."""
+    covered = jnp.all(adj[:, x] == adj[:, y])
+    swap = (x > y) & covered & reverse_ok
+    return jnp.where(swap, y, x), jnp.where(swap, x, y)
 
 
 def _masked_argmax_mapped(mat: Array, key: Array, n: int):
@@ -544,7 +569,15 @@ def ges_jit_body(data, arities, init_adj, allowed, add_limit,
         adj, reach, D, n_ins, done = state[:5]
         c = state[5] if use_cache else None
         pa_count = adj.sum(axis=0)
-        log_q = adj.astype(jnp.float32).T @ log_r
+        log_q = jnp.dot(adj.astype(jnp.float32).T, log_r,
+                        precision=jax.lax.Precision.HIGHEST)
+
+        def insert_ok(a, b):
+            """Full-n validity of inserting a -> b (the valid mask's entry)."""
+            return (allowed[a, b] & (adj[a, b] == 0) & ~reach[b, a]
+                    & (pa_count[b] < max_parents)
+                    & (log_q[b] + log_r[a] <= log_max_q))
+
         if restricted:
             # same validity predicate as the full-n path, gathered into the
             # (W, n) index space: entry [w, y] tests x = pid_table[y, w] -> y
@@ -560,6 +593,7 @@ def ges_jit_body(data, arities, init_adj, allowed, add_limit,
         idx, best = (_masked_argmax_mapped(masked, pid_key, n) if restricted
                      else _masked_argmax(masked))
         x, y = idx // n, idx % n
+        x, y = _canonical_insert(x, y, adj, insert_ok(y, x))
         do_apply = (best > tol) & (n_ins < add_limit)
 
         new_adj = adj.at[x, y].set(jnp.where(do_apply, 1, adj[x, y]))
@@ -662,11 +696,11 @@ def _sharded_ges_prog(d, ess, max_parents, max_q, r_max, counts_impl, tol,
                             pid_table=pid_table, data_axis_name=DATA_AXIS,
                             cache=cache)
 
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(DATA_AXIS),) +
                  (jax.sharding.PartitionSpec(),) * 6,
-        out_specs=jax.sharding.PartitionSpec()))
+        out_specs=jax.sharding.PartitionSpec(), check_vma=False))
 
 
 def ges_jit(

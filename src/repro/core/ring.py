@@ -80,12 +80,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import partition, score_cache
 from .ges import GESConfig, ges_jit_body
-# One shard_map compat shim for the whole codebase (jax 0.4 check_rep ->
-# 0.6 check_vma rename) lives in core/sweeps; the underscore alias keeps
-# pre-unification importers of this module working.
-from .sweeps import pad_data_rows, shard_map_compat
-
-_shard_map_compat = shard_map_compat
+from .sweeps import pad_data_rows
 # Fusion lives in ONE place (core/fusion.py); the compat names below are
 # re-exported because pre-unification callers imported them from here.
 from .fusion import (fuse_trace, fuse_jit, gho_order_jit,  # noqa: F401
@@ -214,11 +209,12 @@ def build_ring_program(mesh: Mesh, spec: RingSpec, config: GESConfig,
     data_spec = P() if spec.data_axis is None else P(spec.data_axis, None)
     pid_specs = (P(axis, None, None),) if restricted else ()
     stat_specs = (P(axis, None),) if config.family_cache else ()
-    mapped = _shard_map_compat(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(data_spec, P(), P(axis, None, None), P(axis, None, None))
         + pid_specs,
         out_specs=(P(axis, None, None), P(axis), P()) + stat_specs,
+        check_vma=False,
     )
     return jax.jit(mapped)
 

@@ -40,12 +40,11 @@ Backends (selected by ``counts_impl``):
 * ``"fused_pallas"`` — same math with the tiled Pallas kernels
   (``kernels/bdeu_sweep``): insert columns run the joint one-hot
   contraction kernel, and delete columns run the **VMEM-resident** delete
-  kernel (``delete_scores``) — the one current-family (max_q, r) table is
-  accumulated in VMEM scratch and each parent slot's marginal is reduced
-  straight to its BDeu score in-kernel, so the table never round-trips
-  through HBM and only the (n,)/(W,) score column is written back
-  (interpret mode on CPU, compiled on TPU; identical masking/guard
-  conventions to the jnp engines).
+  kernel (``delete_marginals``) — the one current-family (max_q, r) table
+  is accumulated in VMEM scratch and each parent slot's marginal is formed
+  there, so only the (S + 1) slot tables reach HBM; the BDeu reduction is
+  the jnp engine's own (interpret mode on CPU, compiled on TPU; identical
+  masking/guard conventions to the jnp engines).
 
 Convention (stronger than the raw bdeu primitives): returned columns and
 matrices are **masked** — entries that are not a legal toggle (self-loops,
@@ -72,9 +71,9 @@ a 2-D (or, with the ring, 3-D) device mesh:
   rebuilds the global counts.  Ragged m is padded with sentinel rows of
   value ``r_max`` (out of range for every variable — counting-neutral in
   all backends), so sharded sweeps are table-identical to single-device.
-  The VMEM Pallas delete kernel reduces counts to scores in-register
-  (scores are NOT shard-additive), so under data sharding
-  ``"fused_pallas"`` deletes route to the two-step psum-able path.
+  The VMEM Pallas delete kernel marginalizes the *local* family table, so
+  under data sharding ``"fused_pallas"`` deletes route to the two-step
+  psum-able path.
 
 The host-facing switch is ``sweep(..., data_shards=d)``, which pads, builds
 a cached jitted ``shard_map`` over a d-device ``("data",)`` mesh and runs
@@ -117,19 +116,6 @@ DATA_AXIS = "data"
 
 KIND_CODES = {"insert": score_cache.KIND_INSERT,
               "delete": score_cache.KIND_DELETE}
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions (collectives validate replication
-    rules we intentionally break: psum-of-counts produces replicated outputs
-    the checker cannot see).  Disables check_rep/check_vma where present."""
-    from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-    except TypeError:  # pragma: no cover - newer jax renamed the flag
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
 
 
 def pad_data_rows(data: Array, r_max: int, d: int) -> Array:
@@ -306,11 +292,10 @@ def sweep_matrix_restricted_body(data, arities, adj, pid_table, ess, max_q,
                                  r_max, counts_impl, kind,
                                  data_axis_name=data_axis_name)
 
-    if counts_impl == "fused" and child_chunk is None:
+    if counts_impl in bdeu.FUSED_IMPLS and child_chunk is None:
         # Same memory bound as bdeu._deltas_impl: a fused child column
-        # materializes an (m, W*r_max) one-hot — map children sequentially so
-        # one slab lives at a time.  ("fused_pallas" builds one-hots
-        # in-kernel and cannot ride lax.map on jax 0.4.x; it vmaps.)
+        # materializes an (r_max, r_max, max_q, W) count slab — map children
+        # sequentially so one slab lives at a time.
         child_chunk = 1
 
     def map_children(ids, rows):
@@ -422,7 +407,10 @@ def _sharded_sweep_fn(d: int, mode: str, has_pids: bool, ess, max_q: int,
                 counts_impl, kind, child_chunk, data_axis_name=DATA_AXIS)
         in_specs = (P(DATA_AXIS), P(), P(), P())
 
-    return jax.jit(shard_map_compat(body, mesh, in_specs, P()))
+    # check_vma=False: psum'd counts are replicated in a way the varying-
+    # manual-axes checker cannot see
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(), check_vma=False))
 
 
 # ---------------------------------------------------------------------------

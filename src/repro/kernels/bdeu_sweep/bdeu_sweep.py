@@ -1,59 +1,53 @@
 """Pallas TPU kernels: fused all-candidate sweeps for BDeu deltas.
 
-Two kernels, one per GES phase:
+Two kernels, one per GES phase.  Both follow the layout rules of
+``kernels/bdeu_count``: instance codes arrive as (1, m_pad) lane-major rows,
+one-hots are built transposed from sublane-iota compares (no in-kernel
+relayout), 0/1 one-hot operands are bf16 with f32 accumulation (exact), and
+every block's last two dims are (8, 128)-aligned or span the whole array.
 
 **Insert (FES)** — ``sweep_counts_pallas``.  The candidate sweep for one
-child evaluates all n families (Pa + {x}) at once.  The per-candidate loop
-engine issues n independent ``bdeu_count`` contractions — each a memory-bound
-(max_q, m) @ (m, r_max) matmul using r_max/128 of the MXU lanes.  The
-extended parent configuration factorizes, ``cfg_x = (cfg0, X_x)``, so the
-whole sweep is ONE joint contraction batched over the child's value b:
+child evaluates all n families (Pa + {x}) at once.  The extended parent
+configuration factorizes, ``cfg_x = (cfg0, X_x)``, so the whole sweep is ONE
+joint contraction batched over the child's value b and the candidate's
+value a:
 
-    counts[b, j0, x*r_max + a] = sum_t [child[t]=b][cfg0[t]=j0][data[t,x]=a]
-                               = OH(cfg0 | child=b)^T @ OH_all(data)
+    counts[b, a, j0, x] = sum_t [child[t]=b][cfg0[t]=j0][data[t,x]=a]
+                        = OH(cfg0 | child=b)^T @ [data == a]
 
-i.e. r_max (max_q, m) @ (m, n*r_max) matmuls — full lane utilization, and
-n / r_max fewer dispatches per child than the loop engine.
+i.e. r_max^2 (q_pad, m) @ (m, n_pad) matmuls with full lane utilization.
+The cfg one-hot of child value b is built once per grid step and reused for
+all r_max candidate values: the a-loop runs in-kernel over (TILE_M, 128)
+``data == a`` masks, so no (TILE_M, TILE_N, r_max) one-hot is ever reshaped
+into lanes.
 
-Grid:      (r_max, n_tiles, m_tiles) — m innermost, sequential on TPU, so the
-           (max_q, TILE_N * r_max) accumulator block stays resident in VMEM
-           across the m sweep and is revisited, exactly like ``bdeu_count``.
-BlockSpec: cfg/child tiles (TILE_M,); data tile (TILE_M, TILE_N) int32 —
-           one-hots are built in-kernel from iota compares, so HBM traffic is
-           the int32 data, not the r_max-times-larger one-hot.
-Padding:   out-of-range cfg (>= max_q) or child (>= r_max, the m-padding
-           sentinel) rows produce all-zero one-hot rows and count nothing;
-           padded data columns hold the sentinel r_max and yield all-zero
-           count columns.  Zero-count cells cancel exactly in the BDeu sum
-           (lgamma(N + a) - lgamma(a) = 0 at N = 0), so padding is exact.
-Counting is exact in f32 for m << 2^24, same argument as ``bdeu_count``.
+Grid:      (r_max, n_pad // 128, m_pad // TILE_M) — m innermost and
+           sequential, so the (r_max, q_pad, 128) output block stays resident
+           in VMEM across the m sweep and is revisited.
+Padding:   out-of-range cfg (q_pad) or child (r_max) rows produce all-zero
+           one-hot rows and count nothing; padded data columns hold the
+           sentinel r_max and yield all-zero count columns.  Zero-count cells
+           cancel exactly in the BDeu sum (lgamma(N + a) - lgamma(a) = 0 at
+           N = 0), so padding is exact.
 
-**Delete (BES)** — ``delete_scores_pallas``.  Every candidate table
-``counts(Pa - {x})`` is a *marginalization* of the ONE current-family
-(max_q, r) table over parent slot x (see ``bdeu.fused_delete_scores`` for the
-radix-code algebra).  The two-step fused path builds that table with
-``bdeu_count`` and hands the slab back to jnp, round-tripping it through HBM
-once per column.  This kernel keeps it VMEM-resident end-to-end: the table is
-accumulated into a VMEM scratch across the m grid, and on the final grid step
-each of the <= n_slots parent-slot marginals is formed *in VMEM* and reduced
-straight to its BDeu score — only the (K,) per-candidate score column is ever
-written back.
+**Delete (BES)** — ``delete_marginals_pallas``.  Every candidate table
+``counts(Pa - {x})`` is a *marginalization* of the ONE current-family table
+over parent slot x (see ``bdeu.fused_delete_scores`` for the radix-code
+algebra).  The kernel accumulates that table in a VMEM scratch across the m
+grid and, on the final grid step, forms each parent slot's marginal in VMEM
+and writes the stack of (S + 1) tables — the family table first — so the
+data is read once for the whole delete column.  The BDeu reduction (lgamma)
+stays outside the kernel, in the same jnp function the ``fused`` engine
+uses, so both engines score identical tables with identical code.
 
-Grid:      (m_tiles,) — sequential on TPU; the (max_q, r_pad) scratch
-           accumulator is revisited, exactly like ``bdeu_count``.
-Marginalization:  TPU has no fast gather/scatter, so the digit-sum
-           M[j'] = sum_{t(j0) = j'} counts[j0] with
-           t(j0) = (j0 // (low*ar)) * low + (j0 % low)
-           is a scatter-by-matmul: the (chunk_q, max_q) one-hot of t built
-           from iota compares, contracted against the matching scratch rows
-           (chunked so the one-hot never exceeds a VMEM-friendly block).
-           Identity slots (ar = 1, low = 1) give t = j0 — the base family —
-           so padded slots are exact no-ops, and slot 0 is the base score.
-Output:    out[c] = slot_scores[cand_slot[c]] via a one-hot gather: slot 0
-           (base) for candidates not in Pa (the jnp reference's no-op
-           convention), slot s+1 for the candidate deleting parent slot s.
-The max_q overflow guard (+/-inf) stays in ``bdeu.fused_delete_scores`` —
-identical conventions for the kernel and the jnp reference by construction.
+Grid:      (m_pad // TILE_M,) — sequential; the (r_pad, q_pad) scratch is
+           revisited.
+Marginalization:  TPU has no fast scatter, so the digit-sum
+           M[j'] = sum_{t(j0) = j'} counts[j0] is a scatter-by-matmul against
+           the one-hot of t(j0) (precomputed per slot by the caller), chunked
+           over j0.  Counts exceed bf16's exact-integer range, so that dot
+           runs at HIGHEST (f32) precision.  Identity slots (t = j0) copy the
+           family table, so padded slots are exact no-ops.
 """
 from __future__ import annotations
 
@@ -64,37 +58,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ref import bdeu_table_score
+from ..common import (LANES, NT_DIMS, VMEM_LIMIT_BYTES, accumulate_counts,
+                      onehot_rows)
 
 
-def _kernel(cfg_ref, child_ref, data_ref, out_ref, *, max_q: int, r_max: int):
+def _insert_kernel(cfg_ref, child_ref, data_ref, out_ref, *, r_max: int):
     b = pl.program_id(0)
-    step = pl.program_id(2)
 
-    @pl.when(step == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    cfg = cfg_ref[...]          # (TILE_M,) int32, sentinel max_q on padding
-    child = child_ref[...]      # (TILE_M,) int32, sentinel r_max on padding
-    data = data_ref[...]        # (TILE_M, TILE_N) int32, sentinel r_max cols
-    tile_m = cfg.shape[0]
-    tile_n = data.shape[1]
-
-    # select instances with child value b; others become all-zero one-hot rows
-    sel = jnp.where(child == b, cfg, max_q)
-    q_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_m, max_q), 1)
-    oh_cfg = (sel[:, None] == q_iota).astype(jnp.float32)   # (TILE_M, max_q)
-
-    a_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_m, tile_n, r_max), 2)
-    oh_all = (data[:, :, None] == a_iota).astype(jnp.float32)
-    oh_all = oh_all.reshape(tile_m, tile_n * r_max)         # (TILE_M, TILE_N*r)
-
-    out_ref[...] += jax.lax.dot_general(
-        oh_cfg, oh_all,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[None]
+    q_pad = out_ref.shape[2]
+    # instances with child value b keep their cfg; others hit the sentinel
+    sel = jnp.where(child_ref[...] == b, cfg_ref[...], q_pad)    # (1, TILE_M)
+    oh_cfg = onehot_rows(sel, q_pad)                           # (q_pad, TILE_M)
+    data = data_ref[...]                                       # (TILE_M, 128)
+    for a in range(r_max):
+        out_ref[0, a] += jnp.dot(oh_cfg, (data == a).astype(jnp.bfloat16),
+                                 preferred_element_type=jnp.float32)
 
 
 def sweep_counts_pallas(
@@ -102,166 +84,113 @@ def sweep_counts_pallas(
     child: jax.Array,
     data: jax.Array,
     *,
-    max_q: int,
+    q_pad: int,
     r_max: int,
-    tile_m: int = 256,
-    tile_n: int = 32,
-    interpret: bool = True,
+    tile_m: int,
+    interpret: bool,
 ) -> jax.Array:
-    """(r_max, max_q, n*r_max) f32 joint sweep counts.
+    """(r_max, r_max, q_pad, n_pad) f32 joint sweep counts [b, a, j0, x].
 
-    cfg/child: (m,) int32; data: (m, n) int32.  m % tile_m == 0 and
-    n % tile_n == 0 (callers pad; see ops.sweep_counts).
+    cfg/child: (1, m_pad) int32 rows; data: (m_pad, n_pad) int32 with
+    m_pad % tile_m == 0 and n_pad % 128 == 0 (ops.sweep_counts pads).
     """
-    m, n = data.shape
-    if m % tile_m != 0:
+    m_pad, n_pad = data.shape
+    if m_pad % tile_m != 0 or n_pad % LANES != 0:
         raise ValueError(
-            f"sweep_counts_pallas: m={m} must be a multiple of "
-            f"tile_m={tile_m} (ops.sweep_counts pads)")
-    if n % tile_n != 0:
-        raise ValueError(
-            f"sweep_counts_pallas: n={n} must be a multiple of "
-            f"tile_n={tile_n} (ops.sweep_counts pads)")
-    grid = (r_max, n // tile_n, m // tile_m)
+            f"sweep_counts_pallas: data {data.shape} must be a multiple of "
+            f"({tile_m}, {LANES}) (ops.sweep_counts pads)")
+    row = pl.BlockSpec((1, tile_m), lambda b, c, i: (0, i))
     return pl.pallas_call(
-        functools.partial(_kernel, max_q=max_q, r_max=r_max),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_m,), lambda b, c, i: (i,)),
-            pl.BlockSpec((tile_m,), lambda b, c, i: (i,)),
-            pl.BlockSpec((tile_m, tile_n), lambda b, c, i: (i, c)),
-        ],
-        out_specs=pl.BlockSpec((1, max_q, tile_n * r_max),
-                               lambda b, c, i: (b, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((r_max, max_q, n * r_max), jnp.float32),
+        functools.partial(_insert_kernel, r_max=r_max),
+        grid=(r_max, n_pad // LANES, m_pad // tile_m),
+        in_specs=[row, row,
+                  pl.BlockSpec((tile_m, LANES), lambda b, c, i: (i, c))],
+        out_specs=pl.BlockSpec((1, r_max, q_pad, LANES),
+                               lambda b, c, i: (b, 0, 0, c)),
+        out_shape=jax.ShapeDtypeStruct((r_max, r_max, q_pad, n_pad),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="bdeu_sweep_insert",
     )(cfg, child, data)
 
 
-def _delete_kernel(cfg_ref, child_ref, cand_ref, ar_ref, low_ref, qr_ref,
-                   out_ref, counts_ref, *, max_q: int, r_pad: int,
-                   n_slots: int, ess: float, chunk_q: int):
+def _delete_kernel(cfg_ref, child_ref, tmap_ref, out_ref, acc_ref, *,
+                   chunk_q: int):
     step = pl.program_id(0)
 
     @pl.when(step == 0)
     def _zero():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # ---- phase 1: accumulate the current-family table into VMEM scratch ----
-    cfg = cfg_ref[...]          # (TILE_M,) int32, sentinel max_q on padding
-    child = child_ref[...]      # (TILE_M,) int32
-    tile_m = cfg.shape[0]
-    q_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_m, max_q), 1)
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_m, r_pad), 1)
-    oh_cfg = (cfg[:, None] == q_iota).astype(jnp.float32)
-    oh_child = (child[:, None] == r_iota).astype(jnp.float32)
-    counts_ref[...] += jax.lax.dot_general(
-        oh_cfg, oh_child,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    accumulate_counts(acc_ref, cfg_ref[...], child_ref[...])
 
-    # ---- phase 2 (final step): marginalize + reduce, all in VMEM ----------
+    # ---- phase 2 (final step): every slot's marginal, formed in VMEM -------
     @pl.when(step == pl.num_programs(0) - 1)
-    def _reduce():
-        qr = qr_ref[...]                   # [q0, q_del_0..q_del_{S-1}, r]
-        r = qr[n_slots + 1]
+    def _marginalize():
+        r_pad, q_pad = acc_ref.shape
+        out_ref[0] = acc_ref[...]
 
-        def bdeu(tbl, q):
-            # THE shared reduction (plain jnp, traces in-kernel): zero-count
-            # rows/cells (incl. the r_pad padding columns) contribute 0
-            return bdeu_table_score(tbl, q, r, ess)
-
-        slot_scores = [bdeu(counts_ref[...], qr[0])]     # slot 0: base family
-        ar_v = ar_ref[...]
-        low_v = low_ref[...]
-        for s in range(n_slots):
-            ar = ar_v[s]
-            low = low_v[s]
-
-            def chunk_body(c, M):
-                # rows j0 in [c*chunk_q, (c+1)*chunk_q) scatter to t(j0);
-                # one-hot-matmul instead of scatter (TPU-native).  When
-                # chunk_q does not divide max_q the last chunk is shifted
-                # back to stay in bounds and its already-processed overlap
-                # rows are masked to the sel-row-zero sentinel.
-                start = jnp.minimum(c * chunk_q, max_q - chunk_q)
-                j0 = (jax.lax.broadcasted_iota(
-                    jnp.int32, (chunk_q, max_q), 0) + start)
-                t = (j0 // (low * ar)) * low + (j0 % low)
-                t = jnp.where(j0 >= c * chunk_q, t, max_q)
-                jp = jax.lax.broadcasted_iota(jnp.int32, (chunk_q, max_q), 1)
-                sel = (t == jp).astype(jnp.float32)      # (chunk_q, max_q)
-                rows = pl.load(counts_ref,
-                               (pl.ds(start, chunk_q), slice(None)))
-                return M + jax.lax.dot_general(
-                    sel, rows,
-                    dimension_numbers=(((0,), (0,)), ((), ())),
+        def slot(s, carry):
+            def chunk(c, marg):
+                # rows j0 in [start, start + chunk_q) scatter to t(j0)
+                start = pl.multiple_of(c * chunk_q, chunk_q)
+                t = tmap_ref[pl.ds(s, 1), pl.ds(start, chunk_q)]  # (1, chunk)
+                sel = onehot_rows(t, q_pad, jnp.float32)       # (q_pad, chunk)
+                rows = acc_ref[:, pl.ds(start, chunk_q)]       # (r_pad, chunk)
+                return marg + jax.lax.dot_general(
+                    rows, sel, NT_DIMS,
+                    precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32)
 
-            n_chunks = -(-max_q // chunk_q)
-            M = jax.lax.fori_loop(0, n_chunks, chunk_body,
-                                  jnp.zeros((max_q, r_pad), jnp.float32))
-            slot_scores.append(bdeu(M, qr[1 + s]))
+            out_ref[s + 1] = jax.lax.fori_loop(
+                0, q_pad // chunk_q, chunk,
+                jnp.zeros((r_pad, q_pad), jnp.float32))
+            return carry
 
-        sv = jnp.stack(slot_scores)                      # (n_slots + 1,)
-        cand = cand_ref[...]                             # (K_pad,) slot ids
-        k_pad = cand.shape[0]
-        s_iota = jax.lax.broadcasted_iota(jnp.int32, (k_pad, n_slots + 1), 1)
-        oh = (cand[:, None] == s_iota).astype(jnp.float32)
-        out_ref[...] = jnp.sum(oh * sv[None, :], axis=1)
+        jax.lax.fori_loop(0, tmap_ref.shape[0], slot, 0)
 
 
-def delete_scores_pallas(
+def delete_marginals_pallas(
     cfg: jax.Array,
     child: jax.Array,
-    cand_slot: jax.Array,
-    slot_ar: jax.Array,
-    slot_low: jax.Array,
-    qr: jax.Array,
+    tmap: jax.Array,
     *,
-    max_q: int,
     r_pad: int,
-    ess: float,
-    tile_m: int = 256,
-    interpret: bool = True,
+    tile_m: int,
+    interpret: bool,
 ) -> jax.Array:
-    """(K,) BDeu scores of the delete-candidate families, VMEM-resident.
+    """(S + 1, r_pad, q_pad) f32 transposed tables: [0] the family table,
+    [s + 1] its marginal over parent slot s.
 
-    cfg/child: (m,) int32, m % tile_m == 0 (cfg sentinel max_q on padding).
-    cand_slot: (K,) int32 — 0 for candidates not in Pa (score = base family),
-    s+1 for the candidate that deletes parent slot s.  slot_ar/slot_low:
-    (n_slots,) int32 per-slot arity and radix place value (1/1 = identity
-    padding).  qr: (n_slots + 2,) f32 = [q0, q_del per slot..., r_child].
-    K and n_slots are static via the argument shapes (callers pad; see
-    ops.delete_scores).
+    cfg/child: (1, m_pad) int32 rows, m_pad % tile_m == 0.  tmap: (S, q_pad)
+    int32, row s the digit-sum relabeling t(j0) of slot s (q_pad % 128 == 0).
     """
-    m = cfg.shape[0]
-    if m % tile_m != 0:
+    m_pad = cfg.shape[1]
+    n_slots, q_pad = tmap.shape
+    if m_pad % tile_m != 0 or q_pad % LANES != 0:
         raise ValueError(
-            f"delete_scores_pallas: m={m} must be a multiple of "
-            f"tile_m={tile_m} (ops.delete_scores pads)")
-    n_slots = slot_ar.shape[0]
-    k_pad = cand_slot.shape[0]
-    # One-hot chunk bound: the (chunk_q, max_q) scatter matrix stays <= ~4 MB
-    # of VMEM at max_q = 4096 regardless of divisibility (a non-multiple
-    # max_q gets a shifted, overlap-masked final chunk — see _delete_kernel).
-    chunk_q = min(max_q, 256)
-    grid = (m // tile_m,)
+            f"delete_marginals_pallas: m={m_pad} must be a multiple of "
+            f"tile_m={tile_m} and q_pad={q_pad} of {LANES} "
+            f"(ops.delete_marginals pads)")
+    chunk_q = 256 if q_pad % 256 == 0 else LANES
+    row = pl.BlockSpec((1, tile_m), lambda i: (0, i))
     return pl.pallas_call(
-        functools.partial(_delete_kernel, max_q=max_q, r_pad=r_pad,
-                          n_slots=n_slots, ess=ess, chunk_q=chunk_q),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_m,), lambda i: (i,)),
-            pl.BlockSpec((tile_m,), lambda i: (i,)),
-            pl.BlockSpec((k_pad,), lambda i: (0,)),
-            pl.BlockSpec((n_slots,), lambda i: (0,)),
-            pl.BlockSpec((n_slots,), lambda i: (0,)),
-            pl.BlockSpec((n_slots + 2,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((k_pad,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((k_pad,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((max_q, r_pad), jnp.float32)],
+        functools.partial(_delete_kernel, chunk_q=chunk_q),
+        grid=(m_pad // tile_m,),
+        in_specs=[row, row,
+                  pl.BlockSpec((n_slots, q_pad), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((n_slots + 1, r_pad, q_pad),
+                               lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_slots + 1, r_pad, q_pad),
+                                       jnp.float32),
+        scratch_shapes=[pltpu.VMEM((r_pad, q_pad), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(cfg, child, cand_slot, slot_ar, slot_low, qr)
+        name="bdeu_sweep_delete",
+    )(cfg, child, tmap)

@@ -6,26 +6,14 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .bdeu_sweep import delete_scores_pallas, sweep_counts_pallas
-from .ref import delete_scores_ref, sweep_counts_ref
+from ..common import (LANES, SUBLANES, instance_tile, pad_row,
+                      resolve_interpret, round_up)
+from .bdeu_sweep import delete_marginals_pallas, sweep_counts_pallas
+from .ref import delete_marginals_ref, sweep_counts_ref
 
 
-def _round_up(x: int, mult: int) -> int:
-    return ((x + mult - 1) // mult) * mult
-
-
-def _resolve_interpret(interpret) -> bool:
-    """``interpret=None`` (the default) resolves per-backend at trace time:
-    interpret mode everywhere except an actual TPU, where the validated
-    kernel compiles — so 'interpret on CPU, compiled on TPU' is the
-    behavior, not just the docstring.  An explicit bool wins."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
-
-
-@partial(jax.jit, static_argnames=("max_q", "r_max", "tile_m", "tile_n",
-                                   "interpret", "use_ref", "data_axis_name"))
+@partial(jax.jit, static_argnames=("max_q", "r_max", "tile_m", "interpret",
+                                   "use_ref", "data_axis_name"))
 def sweep_counts(
     cfg: jax.Array,
     child: jax.Array,
@@ -33,52 +21,48 @@ def sweep_counts(
     *,
     max_q: int,
     r_max: int,
-    tile_m: int = 256,
-    tile_n: int = 32,
+    tile_m: int | None = None,
     interpret: bool | None = None,
     use_ref: bool = False,
     data_axis_name: str | None = None,
 ) -> jax.Array:
-    """(r_max, max_q, n*r_max) f32 joint sweep counts for one child.
+    """(r_max, r_max, max_q, n) f32 joint sweep counts for one child.
 
-    counts[b, j0, x*r_max + a] = #(child=b, base-config=j0, X_x=a) — every
+    counts[b, a, j0, x] = #(child=b, X_x=a, base-config=j0) — every
     candidate family's contingency table for the FES sweep in one call.
-    Pads m and n to tile multiples with counting-neutral sentinels (cfg=max_q,
-    child/data=r_max: all-zero one-hot rows/columns) and slices the padding
-    back off; the validated Pallas kernel runs in interpret mode on CPU and
-    compiled on TPU (``interpret=None`` resolves per-backend).
+    Pads m, n and max_q to the kernel's tile boundaries with
+    counting-neutral sentinels (cfg = q_pad, child/data = r_max: all-zero
+    one-hot rows/columns) and slices the padding back off; the kernel runs
+    in interpret mode on the CPU and compiled on a TPU (``interpret=None``
+    resolves per backend).
 
     ``data_axis_name``: inside shard_map with the instance axis sharded, each
     device contracts only its m/d one-hot shard; the joint counts are
     additive over instances, so one ``psum`` over that mesh axis rebuilds the
     global tables before the (m-independent) BDeu reduction.
     """
-    interpret = _resolve_interpret(interpret)
     m, n = data.shape
-    m_pad = _round_up(max(m, tile_m), tile_m)
-    n_pad = _round_up(max(n, tile_n), tile_n)
-    cfg_p = jnp.full((m_pad,), max_q, dtype=jnp.int32).at[:m].set(
-        cfg.astype(jnp.int32))
-    child_p = jnp.full((m_pad,), r_max, dtype=jnp.int32).at[:m].set(
-        child.astype(jnp.int32))
-    data_p = jnp.full((m_pad, n_pad), r_max, dtype=jnp.int32).at[:m, :n].set(
-        data.astype(jnp.int32))
     if use_ref:
-        counts = sweep_counts_ref(cfg_p, child_p, data_p,
-                                  max_q=max_q, r_max=r_max)
+        counts = sweep_counts_ref(cfg, child, data, max_q=max_q, r_max=r_max)
     else:
-        counts = sweep_counts_pallas(cfg_p, child_p, data_p,
-                                     max_q=max_q, r_max=r_max,
-                                     tile_m=tile_m, tile_n=tile_n,
-                                     interpret=interpret)
-    counts = counts[:, :, :n * r_max]
+        interpret = resolve_interpret(interpret)
+        tm = instance_tile(m, tile_m)
+        m_pad = round_up(m, tm)
+        n_pad = round_up(max(n, 1), LANES)
+        q_pad = round_up(max_q, SUBLANES)
+        data_p = jnp.full((m_pad, n_pad), r_max, dtype=jnp.int32).at[
+            :m, :n].set(data.astype(jnp.int32))
+        counts = sweep_counts_pallas(
+            pad_row(cfg, m_pad, q_pad), pad_row(child, m_pad, r_max), data_p,
+            q_pad=q_pad, r_max=r_max, tile_m=tm, interpret=interpret)
+        counts = counts[:, :, :max_q, :n]
     if data_axis_name is not None:
         counts = jax.lax.psum(counts, data_axis_name)
     return counts
 
 
-@partial(jax.jit, static_argnames=("max_q", "r_max", "tile_m", "tile_n",
-                                   "interpret", "use_ref", "data_axis_name"))
+@partial(jax.jit, static_argnames=("max_q", "r_max", "tile_m", "interpret",
+                                   "use_ref", "data_axis_name"))
 def sweep_counts_restricted(
     cfg: jax.Array,
     child: jax.Array,
@@ -87,109 +71,82 @@ def sweep_counts_restricted(
     *,
     max_q: int,
     r_max: int,
-    tile_m: int = 256,
-    tile_n: int = 32,
+    tile_m: int | None = None,
     interpret: bool | None = None,
     use_ref: bool = False,
     data_axis_name: str | None = None,
 ) -> jax.Array:
-    """(r_max, max_q, W*r_max) joint sweep counts over the W candidates in
+    """(r_max, r_max, max_q, W) joint sweep counts over the W candidates in
     ``pids`` only — the restricted-E_i variant for the ring.
 
     The candidate data columns are gathered BEFORE the one-hot contraction,
-    so the kernel's candidate axis (grid width, accumulator block and flops)
-    is W, not n: a ring process with |E_i| ~ n/k allowed parents per column
-    pays a W-wide contraction, tracking the partition exactly like the loop
-    engine's W per-candidate table builds.  The column tile is shrunk to the
-    (padded) W so a narrow restriction does not pay a full default tile.
+    so the kernel's candidate axis (grid width, output block and flops) is
+    W padded to the lane width, not n: a ring process with |E_i| ~ n/k
+    allowed parents per column pays a W-wide contraction.
 
     This is the contraction behind BOTH restricted paths: the host-engine
     driver's per-column ``pids`` sweeps and the compiled ges_jit/shard_map
     ring, whose (n, W) pid_table matrix sweeps call it once per child from
     inside the while_loop (core/sweeps.sweep_matrix_restricted_body).
     """
-    data_w = jnp.take(data, pids, axis=1)
-    w = data_w.shape[1]
-    tn = min(tile_n, _round_up(w, 8))
-    return sweep_counts(cfg, child, data_w, max_q=max_q, r_max=r_max,
-                        tile_m=tile_m, tile_n=tn, interpret=interpret,
-                        use_ref=use_ref, data_axis_name=data_axis_name)
+    return sweep_counts(cfg, child, jnp.take(data, pids, axis=1),
+                        max_q=max_q, r_max=r_max, tile_m=tile_m,
+                        interpret=interpret, use_ref=use_ref,
+                        data_axis_name=data_axis_name)
 
 
-@partial(jax.jit, static_argnames=("ess", "max_q", "r_max", "tile_m",
-                                   "interpret", "use_ref"))
-def delete_scores(
+def slot_relabeling(slot_ar: jax.Array, slot_low: jax.Array,
+                    q_pad: int) -> jax.Array:
+    """(S, q_pad) int32 digit-sum map of each parent slot:
+    t(j0) = (j0 // (low*ar)) * low + (j0 % low) sends row j0 of the family
+    table to its row in the table with that slot summed out (identity for
+    an ar = 1 slot).  t(j0) <= j0, so padded rows stay in range."""
+    j0 = jnp.arange(q_pad, dtype=jnp.int32)[None, :]
+    low = slot_low.astype(jnp.int32)[:, None]
+    ar = slot_ar.astype(jnp.int32)[:, None]
+    return (j0 // (low * ar)) * low + (j0 % low)
+
+
+@partial(jax.jit, static_argnames=("max_q", "r_max", "tile_m", "interpret",
+                                   "use_ref"))
+def delete_marginals(
     cfg: jax.Array,
     child: jax.Array,
-    cand_slot: jax.Array,
     slot_ar: jax.Array,
     slot_low: jax.Array,
-    qr: jax.Array,
     *,
-    ess: float,
     max_q: int,
     r_max: int,
-    tile_m: int = 256,
+    tile_m: int | None = None,
     interpret: bool | None = None,
     use_ref: bool = False,
 ) -> jax.Array:
-    """(K,) BDeu scores of ALL delete candidates of one child — the
-    VMEM-resident BES column.
+    """(S + 1, max_q, r_max) f32 tables for the BES delete column of one
+    child: [0] the current-family table, [s + 1] its marginal over parent
+    slot s — all from ONE pass over the data, with the table VMEM-resident.
 
     cfg/child: (m,) int32 current-family radix codes and child values.
-    cand_slot: (K,) int32 mapping each candidate to its marginalization slot
-    (0 = not a parent -> base-family score), slot_ar/slot_low: (S,) int32
-    per-slot arity/place value (identity 1/1 on padding slots), qr:
-    (S + 2,) f32 = [q0, q_del per slot..., r_child].  The ONE family table is
-    built in VMEM and each slot marginal is reduced to its score without the
-    (max_q, r) slab ever reaching HBM; only this (K,) column is written.
+    slot_ar/slot_low: (S,) int32 per-slot arity and radix place value
+    (1/1 = identity padding slot).  The caller reduces each table to its
+    BDeu score (``bdeu.fused_delete_scores``).
 
-    Pads m to a tile multiple (sentinel cfg = max_q counts nothing) and the
-    candidate axis to the 128-lane boundary (slot 0, sliced back off).  The
-    child axis of the VMEM table is padded to the f32 sublane boundary in
-    interpret mode and the full 128-lane boundary compiled — zero-count
-    padding columns contribute exactly 0 either way.  The validated Pallas
-    kernel runs in interpret mode on CPU and compiled on TPU
-    (``interpret=None`` resolves per-backend); the max_q overflow guard
-    stays in ``bdeu.fused_delete_scores`` (shared with the jnp reference
-    path).
-
-    NOTE: this kernel reduces counts to SCORES in-VMEM, and scores (unlike
-    counts) are not additive over instance shards — so it deliberately takes
-    no ``data_axis_name``.  Under data sharding ``bdeu.fused_delete_scores``
-    routes to the two-step table-build + marginalization path (whose counts
-    CAN be psum'd); this kernel's per-shard accumulation is unchanged.
+    NOTE: the marginals are counts and additive over instances, but the
+    kernel forms them from the *local* family table; under data sharding
+    ``bdeu.fused_delete_scores`` routes to the two-step table-build + psum +
+    marginalization path instead, so this op takes no ``data_axis_name``.
     """
-    interpret = _resolve_interpret(interpret)
-    m = cfg.shape[0]
-    k = cand_slot.shape[0]
-    m_pad = _round_up(max(m, tile_m), tile_m)
-    k_pad = _round_up(max(k, 1), 128)
-    r_pad = _round_up(r_max, 8 if interpret else 128)
-    # Sentinel DATA rows (core/sweeps.pad_data_rows writes r_max into every
-    # column, so child == r_max there) get the same cfg = max_q drop the
-    # m-padding below uses: the VMEM table's child axis is r_pad >= r_max
-    # wide, so an unmasked sentinel row would land in a padding column with
-    # an in-range cfg instead of vanishing.
-    cfg = jnp.where(child.astype(jnp.int32) < r_max,
-                    cfg.astype(jnp.int32), max_q)
-    cfg_p = jnp.full((m_pad,), max_q, dtype=jnp.int32).at[:m].set(
-        cfg.astype(jnp.int32))
-    child_p = jnp.zeros((m_pad,), dtype=jnp.int32).at[:m].set(
-        child.astype(jnp.int32))
-    cand_p = jnp.zeros((k_pad,), dtype=jnp.int32).at[:k].set(
-        cand_slot.astype(jnp.int32))
     if use_ref:
-        scores = delete_scores_ref(cfg_p, child_p, cand_p,
-                                   slot_ar.astype(jnp.int32),
-                                   slot_low.astype(jnp.int32),
-                                   qr.astype(jnp.float32),
-                                   max_q=max_q, r_pad=r_pad, ess=ess)
-    else:
-        scores = delete_scores_pallas(cfg_p, child_p, cand_p,
-                                      slot_ar.astype(jnp.int32),
-                                      slot_low.astype(jnp.int32),
-                                      qr.astype(jnp.float32),
-                                      max_q=max_q, r_pad=r_pad, ess=ess,
-                                      tile_m=tile_m, interpret=interpret)
-    return scores[:k]
+        q_pad = round_up(max_q, LANES)
+        return delete_marginals_ref(
+            cfg, child, slot_relabeling(slot_ar, slot_low, q_pad)[:, :max_q],
+            max_q=max_q, r_max=r_max)
+    interpret = resolve_interpret(interpret)
+    tm = instance_tile(cfg.shape[0], tile_m)
+    m_pad = round_up(cfg.shape[0], tm)
+    q_pad = round_up(max_q, LANES)
+    r_pad = round_up(r_max, SUBLANES)
+    tables = delete_marginals_pallas(
+        pad_row(cfg, m_pad, q_pad), pad_row(child, m_pad, r_pad),
+        slot_relabeling(slot_ar, slot_low, q_pad),
+        r_pad=r_pad, tile_m=tm, interpret=interpret)
+    return tables[:, :r_max, :max_q].transpose(0, 2, 1)

@@ -3,91 +3,49 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.scipy.special import gammaln
 
 
 def sweep_counts_ref(
     cfg: jax.Array, child: jax.Array, data: jax.Array, *, max_q: int, r_max: int
 ) -> jax.Array:
-    """(r_max, max_q, n*r_max) joint counts; out-of-range rows ignored.
+    """(r_max, r_max, max_q, n) joint counts [b, a, j0, x]; rows whose cfg
+    or child is out of range are ignored.
 
-    counts[b, j0, x*r_max + a] = #(child=b, cfg0=j0, X_x=a), via one
-    segment-sum of the (m, n*r_max) one-hot over the joint (b, j0) index.
+    counts[b, a, j0, x] = #(child=b, X_x=a, cfg0=j0), via one segment-sum of
+    the value-major (m, r_max*n) data one-hot over the joint (b, j0) index.
     """
     m, n = data.shape
-    oh_all = jax.nn.one_hot(data, r_max, dtype=jnp.float32).reshape(m, n * r_max)
+    oh_all = jax.nn.one_hot(data, r_max, dtype=jnp.float32)      # (m, n, r)
+    oh_all = oh_all.transpose(0, 2, 1).reshape(m, r_max * n)
     valid = (cfg >= 0) & (cfg < max_q) & (child >= 0) & (child < r_max)
-    idx = jnp.where(valid,
-                    jnp.clip(child, 0, r_max - 1) * max_q
-                    + jnp.clip(cfg, 0, max_q - 1),
-                    r_max * max_q)
-    counts = jax.ops.segment_sum(
-        jnp.where(valid[:, None], oh_all, 0.0), idx,
-        num_segments=r_max * max_q + 1)
-    return counts[:r_max * max_q].reshape(r_max, max_q, n * r_max)
+    idx = jnp.where(valid, child * max_q + cfg, r_max * max_q)
+    counts = jax.ops.segment_sum(oh_all, idx,
+                                 num_segments=r_max * max_q + 1)
+    counts = counts[:r_max * max_q].reshape(r_max, max_q, r_max, n)
+    return counts.transpose(0, 2, 1, 3)
 
 
-def bdeu_table_score(tbl: jax.Array, q, r, ess: float) -> jax.Array:
-    """BDeu score of ONE dense (Q, R) count table with true hyperparameters
-    (q, r) — mirrors ``bdeu._bdeu_from_counts`` for a single family.
-
-    Zero-count rows/cells (dense padding) contribute exactly 0
-    (lgamma(N + a) - lgamma(a) = 0 at N = 0).  This is THE reduction shared
-    by the VMEM-resident Pallas delete kernel and its jnp oracle — plain jnp
-    ops, so it traces inside the kernel and on host alike; keeping it in one
-    place means a numerical tweak cannot make them silently disagree.
-    """
-    a_j = ess / q
-    a_jk = ess / (q * r)
-    n_ij = jnp.sum(tbl, axis=1)
-    term_j = jnp.sum(gammaln(a_j) - gammaln(n_ij + a_j))
-    term_jk = jnp.sum(gammaln(tbl + a_jk) - gammaln(a_jk))
-    return term_j + term_jk
-
-
-def delete_scores_ref(
+def delete_marginals_ref(
     cfg: jax.Array,
     child: jax.Array,
-    cand_slot: jax.Array,
-    slot_ar: jax.Array,
-    slot_low: jax.Array,
-    qr: jax.Array,
+    tmap: jax.Array,
     *,
     max_q: int,
-    r_pad: int,
-    ess: float,
+    r_max: int,
 ) -> jax.Array:
-    """(K,) delete-candidate BDeu scores; the jnp oracle for
-    ``delete_scores_pallas`` (same contract, segment-sum realization).
+    """(S + 1, max_q, r_max) tables; the jnp oracle for
+    ``delete_marginals_pallas`` (same contract, segment-sum realization).
 
-    Builds the ONE current-family (max_q, r_pad) table (out-of-range rows
-    ignored, like ``sweep_counts_ref``), marginalizes it per parent slot with
-    the digit-sum relabeling t(j0) = (j0 // (low*ar)) * low + (j0 % low),
-    reduces each marginal to its BDeu score with the slot's (q_del, r)
-    hyperparameters, and gathers per candidate through ``cand_slot`` —
-    slot 0 is the unmarginalized base family.
+    Builds the ONE current-family (max_q, r_max) table (out-of-range rows
+    ignored), then sums it per parent slot s under the digit-sum relabeling
+    ``tmap[s]`` (ops.slot_relabeling).
     """
-    n_slots = slot_ar.shape[0]
-    valid = (cfg >= 0) & (cfg < max_q) & (child >= 0) & (child < r_pad)
-    flat = jnp.where(valid,
-                     jnp.clip(cfg, 0, max_q - 1) * r_pad
-                     + jnp.clip(child, 0, r_pad - 1),
-                     max_q * r_pad)
+    valid = (cfg >= 0) & (cfg < max_q) & (child >= 0) & (child < r_max)
+    flat = jnp.where(valid, cfg * r_max + child, max_q * r_max)
     counts = jax.ops.segment_sum(
         jnp.ones_like(flat, dtype=jnp.float32), flat,
-        num_segments=max_q * r_pad + 1)[:max_q * r_pad]
-    counts = counts.reshape(max_q, r_pad)
-
-    r = qr[n_slots + 1]
-    j0 = jnp.arange(max_q, dtype=jnp.int32)
-
-    def slot_score(s):
-        ar, low = slot_ar[s], slot_low[s]
-        t = (j0 // (low * ar)) * low + (j0 % low)
-        marg = jax.ops.segment_sum(counts, t, num_segments=max_q)
-        return bdeu_table_score(marg, qr[1 + s], r, ess)
-
-    scores = [bdeu_table_score(counts, qr[0], r, ess)]
-    for s in range(n_slots):
-        scores.append(slot_score(s))
-    return jnp.take(jnp.stack(scores), cand_slot)
+        num_segments=max_q * r_max + 1)[:max_q * r_max]
+    counts = counts.reshape(max_q, r_max)
+    marginals = [jax.ops.segment_sum(counts, t, num_segments=max_q)
+                 for t in tmap]
+    return jnp.stack([counts] + marginals)

@@ -41,6 +41,7 @@ from ..core.cges import edge_add_limit
 from ..core.dag import smhd_np
 from ..data.bn import benchmark_bn, forward_sample
 from . import devices
+from .compile_cache import enable_compile_cache
 
 
 def ring_rounds(data, arities, edge_masks, config, add_limit, max_rounds,
@@ -184,6 +185,7 @@ def main():
     need = (args.k * args.data_shards if args.engine == "ring"
             else args.data_shards)
     devices.force_host_devices_or_reexec(need, "repro.launch.cges_run")
+    enable_compile_cache()
 
     t0 = time.time()
     bn = benchmark_bn(args.family, scale=args.scale, seed=args.seed)

@@ -5,6 +5,8 @@ XLA fixes its device count when the backend initializes — which importing
 ``repro.core`` already did by the time a driver parses its arguments — so a
 driver that discovers it needs a wider host platform must re-exec itself
 once with ``--xla_force_host_platform_device_count`` in ``XLA_FLAGS``.
+Forced devices exist only on the CPU backend: on an accelerator that has
+too few devices the launcher stops instead of falling back to the CPU.
 That logic used to be grown ad hoc per flag (``--engine ring``,
 ``--data-shards``, ``--family-cache``) inside ``cges_run``; it lives here
 now, and the same helper carries the ``jax.distributed`` coordinator
@@ -31,7 +33,8 @@ def force_host_devices_or_reexec(
     ``extra_env`` entries are exported before the re-exec (e.g. the
     ``jax.distributed`` coordinator triplet for a multi-process launch).
     Raises ``SystemExit`` if the device count was already forced and is
-    still too small — re-exec'ing again would loop forever.
+    still too small — re-exec'ing again would loop forever — and
+    ``RuntimeError`` on any backend but the CPU, whose devices are real.
     """
     if need <= 1:
         return
@@ -39,6 +42,12 @@ def force_host_devices_or_reexec(
 
     if len(jax.devices()) >= need:
         return
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{module} needs >= {need} devices but this {backend} host has "
+            f"{len(jax.devices())}; forced host devices exist only on the "
+            f"CPU backend")
     flags = os.environ.get("XLA_FLAGS", "")
     if "host_platform_device_count" in flags:
         raise SystemExit(

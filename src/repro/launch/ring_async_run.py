@@ -1,6 +1,6 @@
 """Multi-process async elastic ring launcher.
 
-    PYTHONPATH=src python -m repro.launch.ring_async_run \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.ring_async_run \
         --family link_like --scale 0.02 --m 400 --k 2 --max-rounds 4
 
 The parent samples the benchmark BN, partitions the edges, allocates one
@@ -105,6 +105,26 @@ def _free_ports(count: int):
     return ports
 
 
+def _check_cpu_members() -> None:
+    """Members are separate OS processes and an accelerator belongs to one
+    process at a time, so they run on the CPU backend only — and the parent
+    must not hold an accelerator either."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            "ring_async_run starts one OS process per ring member, and an "
+            "accelerator belongs to one process at a time, so its members "
+            "run on the CPU backend only: set JAX_PLATFORMS=cpu.  On a TPU "
+            "host run the members as threads of one process with "
+            "cges(engine='async').")
+    jax = sys.modules.get("jax")
+    if jax is not None and jax.config.jax_platforms != "cpu":
+        raise RuntimeError(
+            f"the launching process imported JAX for platforms "
+            f"{jax.config.jax_platforms!r} before spawning the members; it "
+            f"may hold the accelerator — launch from a process with "
+            f"JAX_PLATFORMS=cpu set before JAX is imported")
+
+
 def launch_ring(data, arities, edge_masks, *, config_kwargs, add_limit=None,
                 max_rounds=16, speculation=2, hb_timeout_s=3.0,
                 wall_limit_s=300.0, jax_distributed=False, die_member=None,
@@ -113,9 +133,11 @@ def launch_ring(data, arities, edge_masks, *, config_kwargs, add_limit=None,
 
     Returns the same aggregate shape as
     ``core.ring_async.run_ring_async_threads`` (graphs/scores/rounds/
-    survivors/members/...), plus per-member exit codes."""
+    survivors/members/...), plus per-member exit codes.  Refuses to start
+    unless the members (and this process) are on the CPU backend."""
     from .devices import jax_distributed_env
 
+    _check_cpu_members()
     if jax_distributed and die_member is not None:
         raise ValueError(
             "--jax-distributed cannot be combined with a kill drill: the "
@@ -150,7 +172,6 @@ def launch_ring(data, arities, edge_masks, *, config_kwargs, add_limit=None,
                                     else None),
             }, f)
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         if coordinator is not None:
             env.update(jax_distributed_env(coordinator, k, i))
         procs.append(subprocess.Popen(

@@ -1,4 +1,4 @@
-"""VMEM-resident Pallas delete sweep (kernels/bdeu_sweep.delete_scores):
+"""VMEM-resident Pallas delete sweep (kernels/bdeu_sweep.delete_marginals):
 kernel == jnp oracle == loop/segment engines over random arities, padded
 r_max, empty parent sets, the max_q +/-inf guard and restricted-W pids —
 through both the column and full-matrix sweep entry points — plus a seeded
@@ -45,10 +45,10 @@ def _random_case(seed, n_lo=4, n_hi=10):
 @given(st.integers(min_value=0, max_value=10**6))
 @settings(max_examples=6, deadline=None)
 def test_delete_kernel_matches_ref_oracle(seed):
-    """delete_scores (Pallas interpret) == delete_scores_ref (segment-sum
-    oracle) on random families, including identity-padded slots and
-    m/candidate padding."""
-    from repro.kernels.bdeu_sweep import delete_scores
+    """delete_marginals (Pallas interpret) == its segment-sum oracle
+    exactly on random families, including identity-padded slots and m
+    padding, and the tables score to the host oracle's deletions."""
+    from repro.kernels.bdeu_sweep import delete_marginals
 
     rng, n, arities, data, _ = _random_case(seed)
     max_q = 64
@@ -71,22 +71,20 @@ def test_delete_kernel_matches_ref_oracle(seed):
     low_s = np.ones(n_slots, np.int32)
     ar_s[:ids.size] = slot_ar[ids]
     low_s[:ids.size] = low[ids]
-    qr = np.zeros(n_slots + 2, np.float32)
-    qr[0] = float(q0)
-    qr[1:n_slots + 1] = float(q0) / ar_s
-    qr[n_slots + 1] = float(arities[child])
+    q_slots = np.concatenate([[int(q0)], int(q0) // ar_s])
     cand_slot = np.zeros(n, np.int32)
     cand_slot[ids] = 1 + np.arange(ids.size)
 
-    kw = dict(ess=10.0, max_q=max_q, r_max=int(arities.max()))
-    got = np.asarray(delete_scores(
-        cfgc, child_col, jnp.asarray(cand_slot), jnp.asarray(ar_s),
-        jnp.asarray(low_s), jnp.asarray(qr), **kw))
-    want = np.asarray(delete_scores(
-        cfgc, child_col, jnp.asarray(cand_slot), jnp.asarray(ar_s),
-        jnp.asarray(low_s), jnp.asarray(qr), use_ref=True, **kw))
-    assert got.shape == want.shape == (n,)
-    assert np.allclose(got, want, rtol=1e-5, atol=1e-4), seed
+    kw = dict(max_q=max_q, r_max=int(arities.max()))
+    tables = delete_marginals(cfgc, child_col, jnp.asarray(ar_s),
+                              jnp.asarray(low_s), **kw)
+    want = delete_marginals(cfgc, child_col, jnp.asarray(ar_s),
+                            jnp.asarray(low_s), use_ref=True, **kw)
+    assert tables.shape == want.shape == (n_slots + 1, max_q, kw["r_max"])
+    assert np.array_equal(np.asarray(tables), np.asarray(want)), seed
+    slot_scores = bdeu._bdeu_from_counts(tables, jnp.asarray(q_slots),
+                                         int(arities[child]), 10.0)
+    got = np.asarray(slot_scores)[cand_slot]
     # per-family host oracle at each real deletion
     base = bdeu.local_score_np(data, arities, child, list(np.sort(pa)))
     assert np.allclose(got[cand_slot == 0], base, rtol=1e-4, atol=2e-3)

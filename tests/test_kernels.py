@@ -50,8 +50,7 @@ def test_bdeu_sweep_matches_ref(seed, m, r, q, n):
     cfg = jax.random.randint(k1, (m,), 0, q, dtype=jnp.int32)
     child = jax.random.randint(k2, (m,), 0, r, dtype=jnp.int32)
     data = jax.random.randint(k3, (m, n), 0, r, dtype=jnp.int32)
-    got = sweep_counts(cfg, child, data, max_q=q, r_max=r,
-                       tile_m=128, tile_n=16)
+    got = sweep_counts(cfg, child, data, max_q=q, r_max=r, tile_m=128)
     want = sweep_counts(cfg, child, data, max_q=q, r_max=r, use_ref=True)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
@@ -66,10 +65,10 @@ def test_bdeu_sweep_total_mass_and_blocks():
     child = jax.random.randint(k2, (m,), 0, r, dtype=jnp.int32)
     data = jax.random.randint(k3, (m, n), 0, r, dtype=jnp.int32)
     counts = np.asarray(sweep_counts(cfg, child, data, max_q=q, r_max=r))
-    assert counts.shape == (r, q, n * r)
+    assert counts.shape == (r, r, q, n)
     assert float(counts.sum()) == float(m * n)
     child_np = np.asarray(child)
-    per_b = counts.reshape(r, q, n, r).sum(axis=(1, 3))  # (b, x)
+    per_b = counts.sum(axis=(1, 2))                      # (b, x)
     for b in range(r):
         assert np.all(per_b[b] == np.sum(child_np == b))
 

@@ -173,15 +173,14 @@ def test_ring_allreduce_matches_psum():
         from jax.sharding import Mesh, PartitionSpec as P
         import sys
         sys.path.insert(0, "src")
-        from repro.core.ring import _shard_map_compat
         from repro.training.compress import ring_allreduce
         mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
         x = jnp.arange(4 * 6, dtype=jnp.float32).reshape(4, 6)
         def body(xl):
             return ring_allreduce(xl[0], "dp", 4)[None]
-        f = jax.jit(_shard_map_compat(body, mesh=mesh,
-                                      in_specs=(P("dp", None),),
-                                      out_specs=P("dp", None)))
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
+                                  in_specs=(P("dp", None),),
+                                  out_specs=P("dp", None), check_vma=False))
         out = np.asarray(f(x))
         want = np.broadcast_to(x.sum(0), (4, 6))
         assert np.allclose(out, want), (out, want)

@@ -173,8 +173,8 @@ def test_restricted_column_matches_loop(mixed_case, kind, impl):
 
 
 def test_restricted_kernel_contracts_w_columns_not_n(mixed_case):
-    """The restricted Pallas variant's counts slab is (r_max, max_q,
-    W*r_max): the contraction width — and hence fused cost — scales with the
+    """The restricted Pallas variant's counts slab is (r_max, r_max, max_q,
+    W): the contraction width — and hence fused cost — scales with the
     candidate subset W, not the full n."""
     from repro.kernels.bdeu_sweep import sweep_counts, sweep_counts_restricted
 
@@ -187,11 +187,11 @@ def test_restricted_kernel_contracts_w_columns_not_n(mixed_case):
     pids = jnp.asarray(np.array([1, 3, 4], dtype=np.int32))
     full = sweep_counts(cfg, child, dj, max_q=32, r_max=r_max)
     sub = sweep_counts_restricted(cfg, child, dj, pids, max_q=32, r_max=r_max)
-    assert full.shape == (r_max, 32, n * r_max)
-    assert sub.shape == (r_max, 32, 3 * r_max)
+    assert full.shape == (r_max, r_max, 32, n)
+    assert sub.shape == (r_max, r_max, 32, 3)
     # gathered-before-contraction == gathered-after-contraction
-    want = np.asarray(full).reshape(r_max, 32, n, r_max)[:, :, np.asarray(pids)]
-    assert np.array_equal(np.asarray(sub).reshape(r_max, 32, 3, r_max), want)
+    want = np.asarray(full)[..., np.asarray(pids)]
+    assert np.array_equal(np.asarray(sub), want)
 
 
 def test_sweep_matrix_rejects_pids():
@@ -461,10 +461,11 @@ def test_ring_cges_trajectory_k3_k4():
     restricted W-wide pid_table path and the persistent family cache each
     exercised on the multi-hop cycle (subprocess: forced host devices).
 
-    max_q=256 keeps every fused-init family under the compiled-table guard
-    for these seeds: when the guard bites a base family but not its
-    reduced families, host and compiled BES legitimately diverge (see
-    bdeu.graph_score_jax) and the cross-engine pin would be vacuous."""
+    max_q=512 keeps every fused-init family (largest q = 324 for these
+    seeds) under the compiled-table guard: when the guard bites a base
+    family but not its reduced families, host and compiled BES
+    legitimately diverge (see bdeu.graph_score_jax) and the cross-engine
+    pin would be vacuous."""
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -508,7 +509,7 @@ def test_ring_cges_trajectory_k3_k4():
             masks = partition.partition_edges(data, bn.arities, k)
             mesh = Mesh(np.array(jax.devices()[:k]), ("ring",))
             spec = RingSpec(k=k, max_rounds=MAX_ROUNDS)
-            cfg = GESConfig(max_q=256, counts_impl=impl)
+            cfg = GESConfig(max_q=512, counts_impl=impl)
             # restricted (W-wide pid_table) vs full-n on the k-cycle
             gW, sW, rW = ring_cges(data, bn.arities, masks, mesh,
                                    spec, cfg, restricted=True)
@@ -518,7 +519,7 @@ def test_ring_cges_trajectory_k3_k4():
             assert np.allclose(sW, sF, rtol=1e-6), (k,)
             assert rW == rF, (k,)
             # persistent family cache on the multi-hop cycle: bitwise pin
-            cfg_fc = GESConfig(max_q=256, counts_impl=impl,
+            cfg_fc = GESConfig(max_q=512, counts_impl=impl,
                                family_cache=True)
             gC, sC, rC, stats = ring_cges(data, bn.arities, masks, mesh,
                                           spec, cfg_fc, restricted=True,
@@ -528,7 +529,7 @@ def test_ring_cges_trajectory_k3_k4():
             assert rC == rW, (k,)
             # host-engine oracle: the cycle's one-hop information flow
             gH, sH, rH = host_ring(masks, k,
-                                   GESConfig(max_q=256,
+                                   GESConfig(max_q=512,
                                              counts_impl="segment"))
             assert np.array_equal(gW, gH), (k, "vs host oracle")
             assert np.allclose(sW, sH, rtol=1e-5, atol=1e-2), (k,)
@@ -541,3 +542,62 @@ def test_ring_cges_trajectory_k3_k4():
                        cwd=os.path.dirname(os.path.dirname(
                            os.path.abspath(__file__))))
     assert "RING_K34_OK" in r.stdout, r.stderr[-3000:]
+
+
+def test_ring_k3_k4_guard_bites_at_max_q_256():
+    """Why test_ring_cges_trajectory_k3_k4 runs at max_q=512: at 256 the
+    compiled-table guard bites one of its fused inits.  The host ring on the
+    same seeds hands GES a fused init holding a q=324 family; the compiled
+    scorer scores that graph -inf at max_q=256 while the float64 scorer and
+    the delete-reduced family stay finite — the base-guarded, reduced-
+    unguarded case in which host and compiled BES diverge
+    (bdeu.graph_score_jax).  At max_q=512 the compiled total is finite and
+    agrees with the float64 scorer."""
+    from repro.core import GESConfig, fusion, ges_host, partition
+
+    rng = np.random.default_rng(5)
+    bn = random_bn(rng, n=9, n_edges=11, max_parents=2)
+    data = forward_sample(bn, 400, rng)
+    n, ar = bn.n, bn.arities
+
+    def family_q(adj):
+        return np.array([int(np.prod(ar[adj[:, y].astype(bool)]))
+                         for y in range(n)])
+
+    q_top, worst = 0, None
+    cfg = GESConfig(max_q=512, counts_impl="segment")
+    for k in (3, 4):
+        masks = partition.partition_edges(data, ar, k)
+        graphs = [np.zeros((n, n), np.int8)] * k
+        best = -np.inf
+        for _ in range(3):
+            inits = [fusion.fusion_edge_union(graphs[i], graphs[(i - 1) % k])
+                     .astype(np.int8) for i in range(k)]
+            for init in inits:
+                if family_q(init).max() > q_top:
+                    q_top, worst = int(family_q(init).max()), init
+            res = [ges_host(data, ar, init_adj=init, allowed=masks[i],
+                            config=cfg) for i, init in enumerate(inits)]
+            graphs = [r.adj for r in res]
+            round_best = max(r.score for r in res)
+            if round_best <= best + cfg.tol:
+                break
+            best = round_best
+    assert q_top == 324
+
+    dj, aj = _jnp(data, ar)
+    exact = bdeu.graph_score_np(data, ar, worst)
+
+    def compiled_total(max_q):
+        return float(bdeu.graph_score_jax(dj, aj, jnp.asarray(worst), 10.0,
+                                          max_q, int(ar.max()), "segment"))
+
+    assert np.isfinite(exact)
+    assert compiled_total(256) == -np.inf
+    assert np.isclose(compiled_total(512), exact, rtol=1e-5)
+    y = int(np.argmax(family_q(worst)))
+    reduced = worst[:, y].astype(bool)
+    reduced[np.flatnonzero(reduced)[0]] = False
+    assert np.isfinite(float(bdeu.local_score_masked(
+        dj, aj, jnp.int32(y), jnp.asarray(reduced), 10.0, 256,
+        int(ar.max()), "segment")))
