@@ -1,0 +1,11 @@
+import os
+import sys
+from pathlib import Path
+
+# The benchmark's tests run on the CPU; only the chip runs measure.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
