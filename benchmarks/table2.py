@@ -64,7 +64,7 @@ def run_algo(name: str, data, arities, config) -> dict:
         limit = "-L-" in name
         r = cges(data, arities, k=k, limit=limit, config=config)
         adj, score, evals = r.adj, r.score, r.n_score_evals
-        extra = {"rounds": r.rounds, "parallel_wall_s": r.parallel_wall_s}
+        extra = {"rounds": r.rounds}
     return dict(adj=adj, score=score, evals=evals,
                 wall_s=time.perf_counter() - t0, **extra)
 
@@ -85,10 +85,6 @@ def bench(families, scale: float, m: int, seeds, algos=ALGOS, verbose=True,
                     "bdeu_per_inst": r["score"] / m,
                     "smhd": smhd_np(r["adj"], bn.adj),
                     "wall_s": round(r["wall_s"], 2),
-                    # k-worker deployment wall (ring rounds concurrent);
-                    # GES/fGES have no ring -> same as serial wall
-                    "wall_par_s": round(r.get("parallel_wall_s",
-                                              r["wall_s"]), 2),
                     "score_evals": r["evals"],
                 }
                 rows.append(row)
@@ -96,7 +92,6 @@ def bench(families, scale: float, m: int, seeds, algos=ALGOS, verbose=True,
                     print(f"  {fam:12s} seed{seed} {algo:9s} "
                           f"BDeu/м={row['bdeu_per_inst']:9.4f} "
                           f"SMHD={row['smhd']:4d} t={row['wall_s']:7.2f}s "
-                          f"t_par={row['wall_par_s']:7.2f}s "
                           f"evals={row['score_evals']}")
     return rows
 
@@ -114,7 +109,6 @@ def summarize(rows):
             "bdeu_per_inst": float(np.mean([r["bdeu_per_inst"] for r in rs])),
             "smhd": float(np.mean([r["smhd"] for r in rs])),
             "wall_s": float(np.mean([r["wall_s"] for r in rs])),
-            "wall_par_s": float(np.mean([r["wall_par_s"] for r in rs])),
             "score_evals": float(np.mean([r["score_evals"] for r in rs])),
         })
     return out

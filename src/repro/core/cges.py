@@ -55,10 +55,11 @@ from typing import List, Optional
 
 import numpy as np
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from . import bdeu, fusion, partition
 from .ges import (DeviceFamilyCache, GESConfig, GESResult, ScoreCache,
-                  ges_host, ges_jit)
+                  ges_host, ges_jit, trace_steps)
 
 
 @dataclasses.dataclass
@@ -70,11 +71,6 @@ class CGESResult:
     wall_time_s: float
     ring_scores: List[float]          # best score per round (trace)
     edge_masks: np.ndarray            # (k, n, n) partition actually used
-    # wall time a k-worker deployment would see: ring rounds cost
-    # max-over-processes (they run concurrently), partition+fine-tune serial.
-    # (this container is 1-core, so the k processes run serially here; the
-    # paper's Table 2c numbers are 8-thread wall times.)
-    parallel_wall_s: float = 0.0
     # hits/misses/hit_rate of the persistent family-score cache, when
     # config.family_cache was on (host engine: the shared DeviceFamilyCache;
     # jax engine: summed per-member cache counters); None otherwise.
@@ -127,7 +123,6 @@ def cges(
             engine="fast",
         )
     add_limit = edge_add_limit(n, k) if limit else None
-    parallel_wall = time.perf_counter() - t0          # stage 1 is serial
 
     graphs = [np.zeros((n, n), dtype=np.int8) for _ in range(k)]
     best_score = -np.inf
@@ -169,107 +164,105 @@ def cges(
         best_adj = np.asarray(ring["best_adj"], dtype=np.int8)
         best_score = float(ring["best_score"])
         evals += int(ring["n_score_evals"])
-        # a real k-process deployment's ring wall time is the slowest
-        # member's own busy+blocked span, not this 1-core serialization
-        parallel_wall += max(
-            sum(float(np.sum(results_i["timings"][ph]))
-                for ph in ("wait_us", "fuse_us", "sweep_us"))
-            for results_i in (ring["members"][i] for i in ring["survivors"])
-        ) / 1e6
         return _finish_cges(
             data, arities, data_j, ar_j, r_max, best_adj,
             config, engine, cache, dev_cache, jax_caches, evals,
-            rounds, ring_scores, edge_masks, parallel_wall, t0)
+            rounds, ring_scores, edge_masks, t0)
 
     rounds = 0
     go = True
+    # Program spans and the ges.steps counter (profiler trace only):
+    # cges.round holds the round's fusions, members and convergence check;
+    # cges.member ends after the member's results are read back to the host.
     while go and rounds < max_rounds:
-        new_graphs: List[np.ndarray] = []
-        new_scores: List[float] = []
-        proc_walls: List[float] = []
-        for i in range(k):
-            tp = time.perf_counter()
-            pred = graphs[(i - 1) % k]
-            if rounds == 0:
-                init = np.zeros((n, n), dtype=np.int8)
-            else:
-                init = fusion.fusion_edge_union(
-                    graphs[i], pred, engine=fusion_engine).astype(np.int8)
-            if engine == "jax":
-                out = ges_jit(
-                    data_j, ar_j, jnp.asarray(init),
-                    jnp.asarray(edge_masks[i].astype(np.int8)),
-                    add_limit=add_limit, config=config, r_max=r_max,
-                    pid_table=pid_j[i], cache=jax_caches[i],
-                    return_cache=config.family_cache)
-                if config.family_cache:
-                    adj_i, score_i, n_ins, n_del, jax_caches[i] = out
+        with TraceAnnotation("cges.round", round=rounds):
+            new_graphs: List[np.ndarray] = []
+            new_scores: List[float] = []
+            for i in range(k):
+                pred = graphs[(i - 1) % k]
+                if rounds == 0:
+                    init = np.zeros((n, n), dtype=np.int8)
                 else:
-                    adj_i, score_i, n_ins, n_del = out
-                adj_i = np.asarray(adj_i)
-                score_i = float(score_i)
-                W = int(pid_j.shape[2])
-                evals += W * n + W * (int(n_ins) + int(n_del))
-            else:
-                res = ges_host(data, arities, init_adj=init,
-                               allowed=edge_masks[i], add_limit=add_limit,
-                               config=config, cache=cache,
-                               family_cache=dev_cache)
-                adj_i, score_i = res.adj, res.score
-                evals += res.n_score_evals
-            new_graphs.append(adj_i)
-            new_scores.append(score_i)
-            proc_walls.append(time.perf_counter() - tp)
-        graphs = new_graphs
-        rounds += 1
-        parallel_wall += max(proc_walls)   # ring processes run concurrently
+                    with TraceAnnotation("cges.fusion", round=rounds,
+                                         member=i):
+                        init = fusion.fusion_edge_union(
+                            graphs[i], pred,
+                            engine=fusion_engine).astype(np.int8)
+                with TraceAnnotation("cges.member", round=rounds, member=i):
+                    if engine == "jax":
+                        out = ges_jit(
+                            data_j, ar_j, jnp.asarray(init),
+                            jnp.asarray(edge_masks[i].astype(np.int8)),
+                            add_limit=add_limit, config=config, r_max=r_max,
+                            pid_table=pid_j[i], cache=jax_caches[i],
+                            return_cache=config.family_cache)
+                        if config.family_cache:
+                            adj_i, score_i, n_ins, n_del, jax_caches[i] = out
+                        else:
+                            adj_i, score_i, n_ins, n_del = out
+                        adj_i = np.asarray(adj_i)
+                        score_i = float(score_i)
+                        n_ins, n_del = int(n_ins), int(n_del)
+                        W = int(pid_j.shape[2])
+                        evals += W * n + W * (n_ins + n_del)
+                    else:
+                        res = ges_host(data, arities, init_adj=init,
+                                       allowed=edge_masks[i],
+                                       add_limit=add_limit, config=config,
+                                       cache=cache, family_cache=dev_cache)
+                        adj_i, score_i = res.adj, res.score
+                        n_ins, n_del = res.n_inserts, res.n_deletes
+                        evals += res.n_score_evals
+                trace_steps(rounds, i, n_ins, n_del)
+                new_graphs.append(adj_i)
+                new_scores.append(score_i)
+            graphs = new_graphs
+            rounds += 1
 
-        # ---- convergence check (Algorithm 1 lines 11-16) ------------------
-        round_best = max(new_scores)
-        ring_scores.append(round_best)
-        if round_best > best_score + config.tol:
-            best_score = round_best
-            best_adj = graphs[int(np.argmax(new_scores))].copy()
-            best_graphs = np.stack(graphs)
-            best_graph_scores = np.asarray(new_scores)
-            go = True
-        else:
-            go = False
+            # ---- convergence check (Algorithm 1 lines 11-16) --------------
+            round_best = max(new_scores)
+            ring_scores.append(round_best)
+            if round_best > best_score + config.tol:
+                best_score = round_best
+                best_adj = graphs[int(np.argmax(new_scores))].copy()
+                best_graphs = np.stack(graphs)
+                best_graph_scores = np.asarray(new_scores)
+                go = True
+            else:
+                go = False
 
     res = _finish_cges(
         data, arities, data_j, ar_j, r_max, best_adj,
         config, engine, cache, dev_cache, jax_caches, evals,
-        rounds, ring_scores, edge_masks, parallel_wall, t0)
+        rounds, ring_scores, edge_masks, t0)
     res.ring_graphs, res.ring_graph_scores = best_graphs, best_graph_scores
     return res
 
 
 def _finish_cges(data, arities, data_j, ar_j, r_max, best_adj,
                  config, engine, cache, dev_cache, jax_caches, evals,
-                 rounds, ring_scores, edge_masks, parallel_wall,
-                 t0) -> CGESResult:
+                 rounds, ring_scores, edge_masks, t0) -> CGESResult:
     """Stage 3 (unrestricted fine-tuning GES from the ring winner) plus
     result assembly — shared by the lockstep round loop and the async-ring
     engine.  The compiled engines ("jax", "async") fine-tune with ges_jit;
     the host engine reuses its shared caches."""
     n = data.shape[1]
-    t_ft = time.perf_counter()
-    if engine in ("jax", "async"):
-        adj_f, score_f, n_ins, n_del = ges_jit(
-            data_j, ar_j, jnp.asarray(best_adj.astype(np.int8)),
-            jnp.ones((n, n), dtype=jnp.int8),
-            add_limit=None, config=config, r_max=r_max)
-        final_adj = np.asarray(adj_f)
-        final_score = float(score_f)
-        evals += n * n + n * (int(n_ins) + int(n_del))
-    else:
-        res = ges_host(data, arities, init_adj=best_adj, allowed=None,
-                       add_limit=None, config=config, cache=cache,
-                       family_cache=dev_cache)
-        final_adj, final_score = res.adj, res.score
-        evals += res.n_score_evals
+    with TraceAnnotation("cges.finetune"):
+        if engine in ("jax", "async"):
+            adj_f, score_f, n_ins, n_del = ges_jit(
+                data_j, ar_j, jnp.asarray(best_adj.astype(np.int8)),
+                jnp.ones((n, n), dtype=jnp.int8),
+                add_limit=None, config=config, r_max=r_max)
+            final_adj = np.asarray(adj_f)
+            final_score = float(score_f)
+            evals += n * n + n * (int(n_ins) + int(n_del))
+        else:
+            res = ges_host(data, arities, init_adj=best_adj, allowed=None,
+                           add_limit=None, config=config, cache=cache,
+                           family_cache=dev_cache)
+            final_adj, final_score = res.adj, res.score
+            evals += res.n_score_evals
 
-    parallel_wall += time.perf_counter() - t_ft       # fine-tune is serial
     fc_stats = None
     if dev_cache is not None:
         fc_stats = dev_cache.stats()
@@ -282,5 +275,5 @@ def _finish_cges(data, arities, data_j, ar_j, r_max, best_adj,
         adj=final_adj, score=final_score, rounds=rounds,
         n_score_evals=evals, wall_time_s=time.perf_counter() - t0,
         ring_scores=ring_scores, edge_masks=edge_masks,
-        parallel_wall_s=parallel_wall, family_cache_stats=fc_stats,
+        family_cache_stats=fc_stats,
     )

@@ -13,6 +13,7 @@ from typing import List
 
 import numpy as np
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from . import bdeu
 
@@ -194,22 +195,24 @@ def partition_edges(
     per-pair oracles, ~1000x fewer dispatches (see EXPERIMENTS §Perf it.0).
     """
     n = data.shape[1]
-    if engine == "host":
-        sims = bdeu.pairwise_similarity_np(data, arities, ess)
-    elif engine == "fast":
-        sims = bdeu.pairwise_similarity_fast(data, arities, ess)
-    elif engine == "jax":
-        r_max = int(arities.max())
-        sims = np.asarray(
-            bdeu.pairwise_similarity_jax(
-                jnp.asarray(data.astype(np.int32)),
-                jnp.asarray(arities.astype(np.int32)),
-                ess, r_max,
+    with TraceAnnotation("partition.similarity"):
+        if engine == "host":
+            sims = bdeu.pairwise_similarity_np(data, arities, ess)
+        elif engine == "fast":
+            sims = bdeu.pairwise_similarity_fast(data, arities, ess)
+        elif engine == "jax":
+            r_max = int(arities.max())
+            sims = np.asarray(
+                bdeu.pairwise_similarity_jax(
+                    jnp.asarray(data.astype(np.int32)),
+                    jnp.asarray(arities.astype(np.int32)),
+                    ess, r_max,
+                )
             )
-        )
-    else:
-        raise ValueError(
-            f"partition_edges: unknown engine {engine!r} "
-            f"(valid: 'host', 'fast', 'jax')")
-    clusters = variable_clusters(sims, k)
-    return edge_subsets(clusters, n)
+        else:
+            raise ValueError(
+                f"partition_edges: unknown engine {engine!r} "
+                f"(valid: 'host', 'fast', 'jax')")
+    with TraceAnnotation("partition.clusters"):
+        clusters = variable_clusters(sims, k)
+        return edge_subsets(clusters, n)

@@ -76,10 +76,11 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import partition, score_cache
-from .ges import GESConfig, ges_jit_body
+from .ges import GESConfig, ges_jit_body, trace_steps
 from .sweeps import pad_data_rows
 # Fusion lives in ONE place (core/fusion.py); the compat names below are
 # re-exported because pre-unification callers imported them from here.
@@ -118,7 +119,9 @@ def _ring_body(data, arities, edge_mask, init_g, pid_table=None,
     per-ring-process family-score cache is threaded through the rounds
     while_loop, so a family scored in round t (or inherited from a
     predecessor's graph) is never recontracted in round t' > t; the body
-    then also returns the final (hits, misses) counters.
+    then also returns the final (hits, misses) counters.  Last it returns
+    the (1, max_rounds, 2) insertions and deletions this process applied in
+    each round (zeros past the executed rounds).
     """
     axis = spec.axis
     k = spec.k
@@ -145,19 +148,17 @@ def _ring_body(data, arities, edge_mask, init_g, pid_table=None,
             data_axis_name=spec.data_axis,
             cache=cache)
         if use_cache:
-            adj, score, _, _, cache = out
-        else:
-            adj, score = out[0], out[1]
-        return adj, score, cache
+            cache = out[4]
+        return out[0], out[1], jnp.stack(out[2:4]), cache
 
     def cond(state):
         go, rnd = state[4], state[5]
         return go & (rnd < spec.max_rounds)
 
     def body(state):
-        g, g_best, s_best, best, go, rnd = state[:6]
-        cache = state[6] if use_cache else None
-        adj, score, cache = one_round(g, cache)
+        g, g_best, s_best, best, go, rnd, steps = state[:7]
+        cache = state[7] if use_cache else None
+        adj, score, n_steps, cache = one_round(g, cache)
         round_best = jax.lax.pmax(score, axis)
         improved = round_best > best + config.tol
         # Keep the graphs of the last GLOBALLY-improving round (Algorithm 1
@@ -167,20 +168,21 @@ def _ring_body(data, arities, edge_mask, init_g, pid_table=None,
         g_keep = jnp.where(improved, adj, g_best)
         s_keep = jnp.where(improved, score, s_best)
         out = (adj, g_keep, s_keep, jnp.maximum(best, round_best),
-               improved, rnd + 1)
+               improved, rnd + 1, steps.at[rnd].set(n_steps))
         return out + (cache,) if use_cache else out
 
-    state0 = (g0, g0, -BIG, -BIG, jnp.bool_(True), jnp.int32(0))
+    state0 = (g0, g0, -BIG, -BIG, jnp.bool_(True), jnp.int32(0),
+              jnp.zeros((spec.max_rounds, 2), jnp.int32))
     if use_cache:
         width = n if pids is None else pids.shape[1]
         state0 = state0 + (score_cache.init(n, width, config.cache_capacity),)
     out = jax.lax.while_loop(cond, body, state0)
-    g_best, s_best, rounds = out[1], out[2], out[5]
+    g_best, s_best, rounds, steps = out[1], out[2], out[5], out[6]
     if use_cache:
-        cache = out[6]
+        cache = out[7]
         hm = jnp.stack([cache.hits, cache.misses])[None]   # (1, 2) per device
-        return g_best[None], s_best[None], rounds, hm
-    return g_best[None], s_best[None], rounds
+        return g_best[None], s_best[None], rounds, hm, steps[None]
+    return g_best[None], s_best[None], rounds, steps[None]
 
 
 def build_ring_program(mesh: Mesh, spec: RingSpec, config: GESConfig,
@@ -200,6 +202,8 @@ def build_ring_program(mesh: Mesh, spec: RingSpec, config: GESConfig,
     caller owns sentinel-padding ragged m (sweeps.pad_data_rows — ring_cges
     does it).  With ``config.family_cache`` the program returns a fourth
     (k, 2) int32 output: per-ring-process (hits, misses) cache counters.
+    The last output is always the (k, max_rounds, 2) int32 insertions and
+    deletions of each ring process in each round.
     """
     axis = spec.axis
 
@@ -213,7 +217,8 @@ def build_ring_program(mesh: Mesh, spec: RingSpec, config: GESConfig,
         body, mesh=mesh,
         in_specs=(data_spec, P(), P(axis, None, None), P(axis, None, None))
         + pid_specs,
-        out_specs=(P(axis, None, None), P(axis), P()) + stat_specs,
+        out_specs=(P(axis, None, None), P(axis), P()) + stat_specs
+        + (P(axis, None, None),),
         check_vma=False,
     )
     return jax.jit(mapped)
@@ -259,25 +264,37 @@ def ring_cges(
     config = config if config is not None else GESConfig()
     r_max = int(arities.max())
     lim = int(n * n if add_limit is None else add_limit)
-    prog = build_ring_program(mesh, spec, config, r_max, lim,
-                              restricted=restricted)
-    data = np.asarray(data)
-    if spec.data_axis is not None and spec.data_axis_size > 1:
-        data = np.asarray(pad_data_rows(data.astype(np.int32), r_max,
-                                        spec.data_axis_size))
-    graphs0 = jnp.zeros((k, n, n), dtype=jnp.int8)
-    args = [
-        jnp.asarray(data.astype(np.int32)),
-        jnp.asarray(arities.astype(np.int32)),
-        jnp.asarray(edge_masks.astype(np.int8)),
-        graphs0,
-    ]
-    if restricted:
-        if pid_tables is None:
-            pid_tables = partition.pid_tables(edge_masks)
-        args.append(jnp.asarray(np.asarray(pid_tables, dtype=np.int32)))
-    out = prog(*args)
-    graphs, scores, rounds = out[0], out[1], out[2]
+    # Program spans (profiler trace only): ring.build ends with the
+    # arguments on the device, ring.launch holds the trace, compile (or
+    # cache load) and enqueue, ring.run the blocking readback.
+    with TraceAnnotation("ring.build"):
+        prog = build_ring_program(mesh, spec, config, r_max, lim,
+                                  restricted=restricted)
+        data = np.asarray(data)
+        if spec.data_axis is not None and spec.data_axis_size > 1:
+            data = np.asarray(pad_data_rows(data.astype(np.int32), r_max,
+                                            spec.data_axis_size))
+        graphs0 = jnp.zeros((k, n, n), dtype=jnp.int8)
+        args = [
+            jnp.asarray(data.astype(np.int32)),
+            jnp.asarray(arities.astype(np.int32)),
+            jnp.asarray(edge_masks.astype(np.int8)),
+            graphs0,
+        ]
+        if restricted:
+            if pid_tables is None:
+                pid_tables = partition.pid_tables(edge_masks)
+            args.append(jnp.asarray(np.asarray(pid_tables, dtype=np.int32)))
+    with TraceAnnotation("ring.launch"):
+        out = prog(*args)
+    with TraceAnnotation("ring.run") as span:
+        graphs, scores = np.asarray(out[0]), np.asarray(out[1])
+        rounds = int(out[2])
+        steps = np.asarray(out[-1])
+        span.set_metadata(rounds=rounds)
+    for r in range(rounds):
+        for i in range(k):
+            trace_steps(r, i, *steps[i, r])
     if return_cache_stats:
         if not config.family_cache:
             raise ValueError("return_cache_stats requires config.family_cache")
@@ -285,5 +302,5 @@ def ring_cges(
         stats = [{"hits": int(h), "misses": int(ms),
                   "hit_rate": float(h) / max(int(h) + int(ms), 1)}
                  for h, ms in hm]
-        return np.asarray(graphs), np.asarray(scores), int(rounds), stats
-    return np.asarray(graphs), np.asarray(scores), int(rounds)
+        return graphs, scores, rounds, stats
+    return graphs, scores, rounds

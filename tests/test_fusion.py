@@ -347,7 +347,8 @@ def test_ring_cges_trajectory_pinned():
 @pytest.mark.slow
 def test_fusion_bench_n400():
     """The n=400 jit fusion step must beat the pre-refactor
-    per-reversal-depth-recompute baseline (the BENCH_sweep.json claim)."""
+    per-reversal-depth-recompute baseline (benchmarks/kernel_bench.py
+    ``bench_fusion``, CPU timings)."""
     bench_dir = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks")
     sys.path.insert(0, bench_dir)

@@ -248,8 +248,7 @@ def test_cges_cached_trajectory_identical(engine):
 def test_ring_cached_trajectory_subprocess():
     """Compiled shard_map ring, cache threaded through the round
     while_loop: trajectory identical to uncached, per-process hit stats
-    returned, hit rate substantial (>= 0.3 at this tiny scale; the
-    BENCH_sweep.json family_cache record pins >= 0.5 at bench scale)."""
+    returned, hit rate substantial (>= 0.3 at this tiny scale)."""
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
