@@ -47,6 +47,7 @@ prior is used (log P(G) = 0), as is standard.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Sequence
 
 import numpy as np
@@ -189,6 +190,65 @@ def _bdeu_from_counts(counts: Array, q, r, ess: float) -> Array:
     term_j = gammaln(a_j) - gammaln(n_ij + a_j)
     term_jk = gammaln(counts + a_jk) - gammaln(a_jk)
     return term_j.sum(-1) + term_jk.sum((-2, -1))
+
+
+# ---------------------------------------------------------------------------
+# Row buckets of the fused sweeps' BDeu reduction
+# ---------------------------------------------------------------------------
+#
+# A fused column's count tables are max_q parent-configuration rows deep,
+# but only the rows below the child's q0 (the configurations of its current
+# parent set) can hold a count; every other row adds an exact 0 to the BDeu
+# sum.  So the fused sweeps reduce only a static bucket of rows covering q0,
+# picked by a lax.switch over a short ladder derived from max_q.  Dropping
+# zero rows changes a score by float32 reassociation of its sum only.  The
+# hyperparameters keep the true q and r, and the count tables, max_q and
+# its -inf overflow guard are untouched.
+
+Q_LADDER_MAX = 5       # buckets at most (lax.switch branches)
+Q_LADDER_MIN = 16      # rows of the smallest bucket
+
+
+def q_ladder(max_q: int) -> tuple:
+    """Static row buckets of the fused reduction, ascending, last = max_q:
+    max_q over powers of 4, at least ``Q_LADDER_MIN`` rows, at most
+    ``Q_LADDER_MAX`` buckets (max_q=1024: 16, 64, 256, 1024).  A max_q
+    below 4 * Q_LADDER_MIN is one bucket."""
+    ladder = [int(max_q)]
+    while len(ladder) < Q_LADDER_MAX and ladder[0] // 4 >= Q_LADDER_MIN:
+        ladder.insert(0, ladder[0] // 4)
+    return tuple(ladder)
+
+
+def parent_configs(arities: Array, parent_mask: Array) -> Array:
+    """float32 number of parent configurations q0 of each masked parent set
+    (parents along the last axis): exact below 2**24 and, unlike the int32
+    radix product, never wraps (inf past float32)."""
+    return jnp.prod(jnp.where(parent_mask, arities, 1).astype(jnp.float32),
+                    axis=-1)
+
+
+def q_bucket(q0: Array, max_q: int) -> Array:
+    """int32 index into :func:`q_ladder` of the smallest bucket holding
+    ``q0`` rows (elementwise); the last bucket (max_q) when q0 > max_q."""
+    edges = jnp.asarray(q_ladder(max_q)[:-1], jnp.float32)
+    q0 = jnp.asarray(q0, jnp.float32)
+    return jnp.sum(q0[..., None] > edges, axis=-1).astype(jnp.int32)
+
+
+def _reduce_rows(reduce, q0: Array, max_q: int, bucket_rows: bool) -> Array:
+    """``reduce(rows)`` (a column's scores from the first ``rows`` rows of
+    its count tables) at the bucket that covers ``q0``.
+
+    ``bucket_rows=False``, or a one-bucket ladder, reduces all max_q rows.
+    Callers pass False where children are batched more than one wide: under
+    vmap a batched switch index turns into a select that runs every branch.
+    """
+    ladder = q_ladder(max_q)
+    if not bucket_rows or len(ladder) == 1:
+        return reduce(max_q)
+    return jax.lax.switch(q_bucket(q0, max_q),
+                          [partial(reduce, rows) for rows in ladder])
 
 
 def _psum_counts(counts: Array, data_axis_name: str | None) -> Array:
@@ -337,6 +397,7 @@ def fused_insert_scores(
     oh_all: Array | None = None,
     pids: Array | None = None,
     data_axis_name: str | None = None,
+    bucket_rows: bool = True,
 ) -> Array:
     """(n,) BDeu scores of ALL candidate families (Pa + {x}) for one child.
 
@@ -363,6 +424,9 @@ def fused_insert_scores(
     ``data_axis_name``: instance axis sharded over that mesh axis — each
     device contracts its m/d shard; one psum rebuilds the global joint
     counts before the (m-independent) BDeu reduction below.
+
+    ``bucket_rows``: reduce only the bucket of table rows that covers q0
+    (:func:`_reduce_rows`); False when the child is batched under vmap.
     """
     cfg0, q0 = _slot_encode(data, arities, parent_mask)
     child_col = jnp.take(data, child, axis=1)
@@ -393,10 +457,16 @@ def fused_insert_scores(
             oh_all = _onehot_all(data_c, r_max)
         counts = _sweep_counts_segment(cfg0c, child_col, oh_all, max_q, r_max)
         counts = _psum_counts(counts, data_axis_name)
-    # (b, a, j0, x) -> per-candidate tables (x, (j0, a), b)
-    slab = counts.transpose(3, 2, 1, 0).reshape(w, max_q * r_max, r_max)
     q = q0.astype(jnp.float32) * ar_c.astype(jnp.float32)         # (w,)
-    scores = _bdeu_from_counts(slab, q, arities[child], ess)
+
+    def reduce(rows):
+        # (b, a, j0 < rows, x) -> per-candidate tables (x, (j0, a), b)
+        slab = counts[:, :, :rows, :].transpose(3, 2, 1, 0).reshape(
+            w, rows * r_max, r_max)
+        return _bdeu_from_counts(slab, q, arities[child], ess)
+
+    scores = _reduce_rows(reduce, parent_configs(arities, parent_mask),
+                          max_q, bucket_rows)
     log_q0 = jnp.sum(jnp.where(parent_mask,
                                jnp.log(arities.astype(jnp.float32)), 0.0))
     ok = (log_q0 + jnp.log(ar_c.astype(jnp.float32))
@@ -415,6 +485,7 @@ def fused_delete_scores(
     counts_impl: str = "fused",
     pids: Array | None = None,
     data_axis_name: str | None = None,
+    bucket_rows: bool = True,
 ) -> Array:
     """(n,) BDeu scores of ALL candidate families (Pa - {x}) for one child,
     from ONE family-table build.
@@ -466,9 +537,13 @@ def fused_delete_scores(
     kernel marginalizes the *local* family table, so under data sharding
     ``"fused_pallas"`` routes to the two-step path (table build via the
     psum-able ``contingency_counts`` wrapper + jnp marginalization).
+
+    ``bucket_rows``: as in :func:`fused_insert_scores`.  Every reduced
+    family's rows lie below q0 too, so one bucket serves the whole column.
     """
     n = data.shape[1]
     cfg0, q0 = _slot_encode(data, arities, parent_mask)
+    q0_rows = parent_configs(arities, parent_mask)
     child_col = jnp.take(data, child, axis=1)
     cfg0c = jnp.clip(cfg0, 0, max_q - 1)
 
@@ -506,7 +581,10 @@ def fused_delete_scores(
         tables = delete_marginals(cfg0c, child_col, ar_s, low_s,
                                   max_q=max_q, r_max=r_max)
         q_slots = jnp.concatenate([q0[None], q0 // ar_s])
-        slot_scores = _bdeu_from_counts(tables, q_slots, arities[child], ess)
+        slot_scores = _reduce_rows(
+            lambda rows: _bdeu_from_counts(tables[:, :rows], q_slots,
+                                           arities[child], ess),
+            q0_rows, max_q, bucket_rows)
         scores = jnp.take(slot_scores, cand_slot)
     else:
         impl = single_impl(counts_impl)
@@ -522,19 +600,23 @@ def fused_delete_scores(
             counts0 = _dense_counts_segment(cfg0c, child_col, r_max, max_q)
             counts0 = _psum_counts(counts0, data_axis_name)
 
-        j0 = jnp.arange(max_q, dtype=jnp.int32)[None, :]             # (1, Q)
-        low_c = low[:, None]
-        hi = j0 // (low_c * slot_ar[:, None])
-        lo = j0 % low_c
-        mapped = hi * low_c + lo                                     # (w, Q)
-        flat = (jnp.arange(w, dtype=jnp.int32)[:, None] * max_q + mapped)
-        tiled = jnp.broadcast_to(counts0, (w,) + counts0.shape)
-        slab = jax.ops.segment_sum(
-            tiled.reshape(w * max_q, r_max), flat.reshape(-1),
-            num_segments=w * max_q).reshape(w, max_q, r_max)
-
         q_del = (q0 // slot_ar).astype(jnp.float32)                  # (w,)
-        scores = _bdeu_from_counts(slab, q_del, arities[child], ess)
+
+        def reduce(rows):
+            # a row's marginal row is never above it: rows < q0 stay < q0
+            j0 = jnp.arange(rows, dtype=jnp.int32)[None, :]          # (1, Q)
+            low_c = low[:, None]
+            hi = j0 // (low_c * slot_ar[:, None])
+            lo = j0 % low_c
+            mapped = hi * low_c + lo                                 # (w, Q)
+            flat = (jnp.arange(w, dtype=jnp.int32)[:, None] * rows + mapped)
+            tiled = jnp.broadcast_to(counts0[:rows], (w, rows, r_max))
+            slab = jax.ops.segment_sum(
+                tiled.reshape(w * rows, r_max), flat.reshape(-1),
+                num_segments=w * rows).reshape(w, rows, r_max)
+            return _bdeu_from_counts(slab, q_del, arities[child], ess)
+
+        scores = _reduce_rows(reduce, q0_rows, max_q, bucket_rows)
 
     log_q0 = jnp.sum(jnp.where(parent_mask,
                                jnp.log(arities.astype(jnp.float32)), 0.0))
@@ -739,6 +821,15 @@ def _deltas_impl(data, arities, adj, ess, max_q, r_max, counts_impl,
     oh_all = (_onehot_all(data, r_max)
               if insert and counts_impl == "fused" else None)
 
+    if counts_impl in FUSED_IMPLS and child_chunk is None:
+        # A fused child sweep materializes a per-child slab — insert: the
+        # (r_max, r_max, max_q, n) joint counts; delete: the (n, max_q,
+        # r_max) marginalization stack.  Map children sequentially so that
+        # slab exists for one child at a time instead of vmapping it n-wide
+        # (n^2-scale peak memory: gigabytes at paper scale).
+        child_chunk = 1
+    bucket_rows = child_chunk == 1             # children mapped unbatched
+
     def per_child_insert_fused(args):
         """Fused insert sweep: ALL n candidate tables from one joint
         contraction (see fused_insert_scores) — the whole per-child loop
@@ -747,7 +838,8 @@ def _deltas_impl(data, arities, adj, ess, max_q, r_max, counts_impl,
         y, pm, b = args
         return fused_insert_scores(
             data, arities, y, pm, ess, max_q, r_max, counts_impl,
-            oh_all=oh_all, data_axis_name=data_axis_name) - b
+            oh_all=oh_all, data_axis_name=data_axis_name,
+            bucket_rows=bucket_rows) - b
 
     def per_child_insert_loop(args):
         """Insert sweep via the ONE loop-engine primitive
@@ -766,7 +858,7 @@ def _deltas_impl(data, arities, adj, ess, max_q, r_max, counts_impl,
         y, pm, b = args
         return fused_delete_scores(
             data, arities, y, pm, ess, max_q, r_max, counts_impl,
-            data_axis_name=data_axis_name) - b
+            data_axis_name=data_axis_name, bucket_rows=bucket_rows) - b
 
     def per_child_delete(args):
         y, pm, b = args
@@ -785,13 +877,6 @@ def _deltas_impl(data, arities, adj, ess, max_q, r_max, counts_impl,
     else:
         per_child = (per_child_delete_fused if counts_impl in FUSED_IMPLS
                      else per_child_delete)
-    if counts_impl in FUSED_IMPLS and child_chunk is None:
-        # A fused child sweep materializes a per-child slab — insert: the
-        # (r_max, r_max, max_q, n) joint counts; delete: the (n, max_q,
-        # r_max) marginalization stack.  Map children sequentially so that
-        # slab exists for one child at a time instead of vmapping it n-wide
-        # (n^2-scale peak memory: gigabytes at paper scale).
-        child_chunk = 1
 
     def base_for(ch, masks):
         return family_scores_batch(
@@ -804,20 +889,28 @@ def _deltas_impl(data, arities, adj, ess, max_q, r_max, counts_impl,
         ids = jnp.clip(i * per + jnp.arange(per), 0, n - 1).astype(jnp.int32)
         masks_l = base_masks[ids]
         base_l = base_for(ids, masks_l)
-        scores_l = jax.lax.map(per_child, (ids, masks_l, base_l),
-                               batch_size=min(child_chunk or per, per))
+        scores_l = map_children(per_child, (ids, masks_l, base_l),
+                                min(child_chunk or per, per))
         scores = jax.lax.all_gather(scores_l, axis_name, axis=0,
                                     tiled=True)[:n]     # (y, x)
         return scores.T
 
     base = base_for(children, base_masks)
-    if child_chunk is None or child_chunk >= n:
-        scores_xy = jax.vmap(per_child)((children, base_masks, base))
-    else:
-        scores_xy = jax.lax.map(
-            per_child, (children, base_masks, base), batch_size=child_chunk
-        )
-    return scores_xy.T
+    return map_children(per_child, (children, base_masks, base),
+                        child_chunk).T
+
+
+def map_children(per_child, xs, chunk):
+    """Map ``per_child`` over the leading axis of the pytree ``xs``,
+    ``chunk`` children at a time: one vmap when ``chunk`` is None or covers
+    them all, else a sequential ``lax.map``.  A chunk of one maps without
+    vmap, so ``per_child`` is traced unbatched and the fused sweeps'
+    row-bucket switch (:func:`_reduce_rows`) stays a branch."""
+    if chunk == 1:
+        return jax.lax.map(per_child, xs)
+    if chunk is None or chunk >= jax.tree.leaves(xs)[0].shape[0]:
+        return jax.vmap(per_child)(xs)
+    return jax.lax.map(per_child, xs, batch_size=chunk)
 
 
 def insert_deltas(

@@ -59,7 +59,7 @@ from jax.profiler import TraceAnnotation
 
 from . import bdeu, fusion, partition
 from .ges import (DeviceFamilyCache, GESConfig, GESResult, ScoreCache,
-                  ges_host, ges_jit, trace_steps)
+                  ges_host, ges_jit, trace_q_buckets, trace_steps)
 
 
 @dataclasses.dataclass
@@ -171,7 +171,8 @@ def cges(
 
     rounds = 0
     go = True
-    # Program spans and the ges.steps counter (profiler trace only):
+    # Program spans and the ges.steps and ges.q_buckets counters (profiler
+    # trace only; ges.q_buckets from the compiled engine):
     # cges.round holds the round's fusions, members and convergence check;
     # cges.member ends after the member's results are read back to the host.
     while go and rounds < max_rounds:
@@ -195,11 +196,12 @@ def cges(
                             jnp.asarray(edge_masks[i].astype(np.int8)),
                             add_limit=add_limit, config=config, r_max=r_max,
                             pid_table=pid_j[i], cache=jax_caches[i],
-                            return_cache=config.family_cache)
+                            return_cache=config.family_cache,
+                            return_q_buckets=True)
+                        adj_i, score_i, n_ins, n_del = out[:4]
                         if config.family_cache:
-                            adj_i, score_i, n_ins, n_del, jax_caches[i] = out
-                        else:
-                            adj_i, score_i, n_ins, n_del = out
+                            jax_caches[i] = out[4]
+                        q_buckets = np.asarray(out[-1])
                         adj_i = np.asarray(adj_i)
                         score_i = float(score_i)
                         n_ins, n_del = int(n_ins), int(n_del)
@@ -214,6 +216,8 @@ def cges(
                         n_ins, n_del = res.n_inserts, res.n_deletes
                         evals += res.n_score_evals
                 trace_steps(rounds, i, n_ins, n_del)
+                if engine == "jax":
+                    trace_q_buckets(rounds, i, q_buckets, config.max_q)
                 new_graphs.append(adj_i)
                 new_scores.append(score_i)
             graphs = new_graphs
@@ -249,10 +253,12 @@ def _finish_cges(data, arities, data_j, ar_j, r_max, best_adj,
     n = data.shape[1]
     with TraceAnnotation("cges.finetune"):
         if engine in ("jax", "async"):
-            adj_f, score_f, n_ins, n_del = ges_jit(
+            adj_f, score_f, n_ins, n_del, q_buckets = ges_jit(
                 data_j, ar_j, jnp.asarray(best_adj.astype(np.int8)),
                 jnp.ones((n, n), dtype=jnp.int8),
-                add_limit=None, config=config, r_max=r_max)
+                add_limit=None, config=config, r_max=r_max,
+                return_q_buckets=True)
+            trace_q_buckets(rounds, -1, np.asarray(q_buckets), config.max_q)
             final_adj = np.asarray(adj_f)
             final_score = float(score_f)
             evals += n * n + n * (int(n_ins) + int(n_del))

@@ -164,6 +164,20 @@ def trace_steps(rnd: int, member: int, n_ins: int, n_del: int) -> None:
         pass
 
 
+def trace_q_buckets(rnd: int, member: int, counts, max_q: int) -> None:
+    """Write the ``ges.q_buckets`` counter to the profiler trace: one
+    instant event with the column reductions one ``ges_jit`` call of ring
+    member ``member`` made in round ``rnd``, per row bucket of
+    ``bdeu.q_ladder(max_q)``: stat ``q<rows>`` counts the columns reduced
+    over ``rows`` rows (``q16``, ``q64``, ...).  Member -1 is the fine-tune
+    after the last round."""
+    stats = {f"q{rows}": int(c)
+             for rows, c in zip(bdeu.q_ladder(max_q), counts)}
+    with jax.profiler.TraceAnnotation(
+            "ges.q_buckets", round=int(rnd), member=int(member), **stats):
+        pass
+
+
 # Device-resident per-dataset arrays, cached across rounds: the host driver
 # used to re-upload the (m, n) code array (and rebuild every derived one-hot
 # from scratch on device) in EVERY ges_host call, although cges/ring_rounds
@@ -484,10 +498,20 @@ def ges_jit_body(data, arities, init_adj, allowed, add_limit,
     (score_cache.FamilyScoreCache, column width W if restricted else n).
     The FES/BES init matrices are then built column-by-column through the
     cache (lax.scan) and the incremental rescoring consults it inside the
-    while_loop carries; the returned tuple gains the final cache state
-    (5-tuple instead of 4).  Under a data axis the cache state is replicated
-    across data-axis devices (identical psum'd columns -> identical
-    evolution), so the hit/miss cond never diverges.
+    while_loop carries; the returned tuple gains the final cache state.
+    Under a data axis the cache state is replicated across data-axis
+    devices (identical psum'd columns -> identical evolution), so the
+    hit/miss cond never diverges.
+
+    Returns ``(adj, score, n_ins, n_del, q_buckets)`` plus the cache state
+    when ``cache`` is given.  ``q_buckets`` is the ``ges.q_buckets``
+    histogram: (len(bdeu.q_ladder(max_q)),) int32 column reductions per row
+    bucket, from the same bucket function the fused sweeps switch on.  It
+    depends on the parent sets alone, whatever ``counts_impl`` (the loop
+    engines reduce all max_q rows all the same).  It counts the n columns
+    of each initial FES and BES matrix and one column per applied step (n
+    per step when not ``incremental``); a family-cache hit, which skips the
+    reduction, still counts.
     """
     n = init_adj.shape[0]
     use_cache = cache is not None
@@ -504,6 +528,23 @@ def ges_jit_body(data, arities, init_adj, allowed, add_limit,
         def gather_wy(mat):
             """(n, n) mask/matrix -> (W, n) entries at [pid_table[y, w], y]."""
             return mat[x_of, ycols]
+
+    n_buckets = len(bdeu.q_ladder(max_q))
+
+    def buckets_of(parent_mask):
+        return bdeu.q_bucket(bdeu.parent_configs(arities, parent_mask), max_q)
+
+    def matrix_buckets(adj):
+        """q_buckets of a whole matrix sweep: one column per child."""
+        return jnp.zeros(n_buckets, jnp.int32).at[
+            buckets_of(adj.astype(bool).T)].add(1)
+
+    def step_buckets(adj, y, applied):
+        """q_buckets of the rescoring after an applied step on column y."""
+        if not incremental:
+            return matrix_buckets(adj) * applied.astype(jnp.int32)
+        return jnp.zeros(n_buckets, jnp.int32).at[
+            buckets_of(adj[:, y].astype(bool))].add(applied.astype(jnp.int32))
 
     def full_insert_D(adj):
         if restricted:
@@ -577,8 +618,8 @@ def ges_jit_body(data, arities, init_adj, allowed, add_limit,
         return ~state[4]
 
     def fes_body(state):
-        adj, reach, D, n_ins, done = state[:5]
-        c = state[5] if use_cache else None
+        adj, reach, D, n_ins, done, qb = state[:6]
+        c = state[6] if use_cache else None
         pa_count = adj.sum(axis=0)
         log_q = jnp.dot(adj.astype(jnp.float32).T, log_r,
                         precision=jax.lax.Precision.HIGHEST)
@@ -622,7 +663,8 @@ def ges_jit_body(data, arities, init_adj, allowed, add_limit,
                 full_D = full_insert_D(new_adj)
             new_D = jnp.where(do_apply, full_D, D)
         out = (new_adj, new_reach, new_D,
-               n_ins + do_apply.astype(jnp.int32), ~do_apply)
+               n_ins + do_apply.astype(jnp.int32), ~do_apply,
+               qb + step_buckets(new_adj, y, do_apply))
         return out + (c,) if use_cache else out
 
     adj0 = init_adj.astype(jnp.int8)
@@ -631,21 +673,22 @@ def ges_jit_body(data, arities, init_adj, allowed, add_limit,
         D0, cache = cached_D(cache, adj0, "insert")
     else:
         D0 = full_insert_D(adj0)
-    state = (adj0, reach0, D0, jnp.int32(0), jnp.bool_(False))
+    state = (adj0, reach0, D0, jnp.int32(0), jnp.bool_(False),
+             matrix_buckets(adj0))
     if use_cache:
         state = state + (cache,)
     fes_out = jax.lax.while_loop(fes_cond, fes_body, state)
-    adj1, n_ins = fes_out[0], fes_out[3]
+    adj1, n_ins, qb = fes_out[0], fes_out[3], fes_out[5]
     if use_cache:
-        cache = fes_out[5]
+        cache = fes_out[6]
 
     # ---------------- BES ----------------
     def bes_cond(state):
         return ~state[3]
 
     def bes_body(state):
-        adj, D, n_del, done = state[:4]
-        c = state[4] if use_cache else None
+        adj, D, n_del, done, qb = state[:5]
+        c = state[5] if use_cache else None
         valid = adj.astype(bool) & allowed
         if restricted:
             valid = gather_wy(valid)
@@ -667,24 +710,26 @@ def ges_jit_body(data, arities, init_adj, allowed, add_limit,
             else:
                 full_D = full_delete_D(new_adj)
             new_D = jnp.where(do_apply, full_D, D)
-        out = (new_adj, new_D, n_del + do_apply.astype(jnp.int32), ~do_apply)
+        out = (new_adj, new_D, n_del + do_apply.astype(jnp.int32), ~do_apply,
+               qb + step_buckets(new_adj, y, do_apply))
         return out + (c,) if use_cache else out
 
     if use_cache:
         D1, cache = cached_D(cache, adj1, "delete")
     else:
         D1 = full_delete_D(adj1)
-    state = (adj1, D1, jnp.int32(0), jnp.bool_(False))
+    state = (adj1, D1, jnp.int32(0), jnp.bool_(False),
+             qb + matrix_buckets(adj1))
     if use_cache:
         state = state + (cache,)
     bes_out = jax.lax.while_loop(bes_cond, bes_body, state)
-    adj2, n_del = bes_out[0], bes_out[2]
+    adj2, n_del, qb = bes_out[0], bes_out[2], bes_out[4]
 
     score = bdeu.graph_score_jax(data, arities, adj2, ess, max_q, r_max,
                                  counts_impl, data_axis_name=data_axis_name)
     if use_cache:
-        return adj2, score, n_ins, n_del, bes_out[4]
-    return adj2, score, n_ins, n_del
+        return adj2, score, n_ins, n_del, qb, bes_out[5]
+    return adj2, score, n_ins, n_del, qb
 
 
 @lru_cache(maxsize=None)
@@ -725,6 +770,7 @@ def ges_jit(
     pid_table: Optional[Array] = None,
     cache: Optional[score_cache.FamilyScoreCache] = None,
     return_cache: bool = False,
+    return_q_buckets: bool = False,
 ):
     """Fully-compiled GES. ``add_limit=None`` means unlimited (n^2 cap).
 
@@ -738,6 +784,8 @@ def ges_jit(
     ``return_cache=True`` to receive ``(adj, score, n_ins, n_del, cache')``
     so the warmed state can seed the next round; the cached trajectory is
     bitwise-identical to the uncached one (exact keys — see core/score_cache).
+    ``return_q_buckets=True`` appends the ``ges.q_buckets`` histogram of
+    column reductions per row bucket (see ges_jit_body) to what is returned.
     """
     config = config if config is not None else GESConfig()
     n = init_adj.shape[0]
@@ -762,6 +810,9 @@ def ges_jit(
             config.ess, config.max_parents, config.max_q, r_max,
             config.counts_impl, config.tol, config.incremental,
             config.child_chunk, cache, jnp.int32(0))
-    if cache is not None and not return_cache:
-        return out[:4]
-    return out
+    res = tuple(out[:4])
+    if cache is not None and return_cache:
+        res += (out[5],)
+    if return_q_buckets:
+        res += (out[4],)
+    return res

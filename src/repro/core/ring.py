@@ -79,8 +79,8 @@ import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import partition, score_cache
-from .ges import GESConfig, ges_jit_body, trace_steps
+from . import bdeu, partition, score_cache
+from .ges import GESConfig, ges_jit_body, trace_q_buckets, trace_steps
 from .sweeps import pad_data_rows
 # Fusion lives in ONE place (core/fusion.py); the compat names below are
 # re-exported because pre-unification callers imported them from here.
@@ -108,7 +108,7 @@ class RingSpec:
 
 def _ring_body(data, arities, edge_mask, init_g, pid_table=None,
                *, spec: RingSpec, config: GESConfig, r_max: int,
-               add_limit: int):
+               add_limit: int, q_buckets: bool = False):
     """Per-device body under shard_map.  edge_mask/init_g: (1, n, n) local;
     pid_table: optional (1, n, W) local — this process's static E_i candidate
     table, making every sweep of every round W-wide (see ges_jit_body).
@@ -119,9 +119,12 @@ def _ring_body(data, arities, edge_mask, init_g, pid_table=None,
     per-ring-process family-score cache is threaded through the rounds
     while_loop, so a family scored in round t (or inherited from a
     predecessor's graph) is never recontracted in round t' > t; the body
-    then also returns the final (hits, misses) counters.  Last it returns
-    the (1, max_rounds, 2) insertions and deletions this process applied in
-    each round (zeros past the executed rounds).
+    then also returns the final (hits, misses) counters.  With
+    ``q_buckets`` it next returns the (1, max_rounds, n_buckets)
+    ``ges.q_buckets`` histogram of each round's GES (see ges_jit_body).
+    Last it returns the (1, max_rounds, 2) insertions and deletions this
+    process applied in each round (zeros past the executed rounds, in
+    both).
     """
     axis = spec.axis
     k = spec.k
@@ -148,17 +151,17 @@ def _ring_body(data, arities, edge_mask, init_g, pid_table=None,
             data_axis_name=spec.data_axis,
             cache=cache)
         if use_cache:
-            cache = out[4]
-        return out[0], out[1], jnp.stack(out[2:4]), cache
+            cache = out[5]
+        return out[0], out[1], jnp.stack(out[2:4]), out[4], cache
 
     def cond(state):
         go, rnd = state[4], state[5]
         return go & (rnd < spec.max_rounds)
 
     def body(state):
-        g, g_best, s_best, best, go, rnd, steps = state[:7]
-        cache = state[7] if use_cache else None
-        adj, score, n_steps, cache = one_round(g, cache)
+        g, g_best, s_best, best, go, rnd, steps, qbs = state[:8]
+        cache = state[8] if use_cache else None
+        adj, score, n_steps, qb, cache = one_round(g, cache)
         round_best = jax.lax.pmax(score, axis)
         improved = round_best > best + config.tol
         # Keep the graphs of the last GLOBALLY-improving round (Algorithm 1
@@ -168,25 +171,30 @@ def _ring_body(data, arities, edge_mask, init_g, pid_table=None,
         g_keep = jnp.where(improved, adj, g_best)
         s_keep = jnp.where(improved, score, s_best)
         out = (adj, g_keep, s_keep, jnp.maximum(best, round_best),
-               improved, rnd + 1, steps.at[rnd].set(n_steps))
+               improved, rnd + 1, steps.at[rnd].set(n_steps),
+               qbs.at[rnd].set(qb))
         return out + (cache,) if use_cache else out
 
+    n_buckets = len(bdeu.q_ladder(config.max_q))
     state0 = (g0, g0, -BIG, -BIG, jnp.bool_(True), jnp.int32(0),
-              jnp.zeros((spec.max_rounds, 2), jnp.int32))
+              jnp.zeros((spec.max_rounds, 2), jnp.int32),
+              jnp.zeros((spec.max_rounds, n_buckets), jnp.int32))
     if use_cache:
         width = n if pids is None else pids.shape[1]
         state0 = state0 + (score_cache.init(n, width, config.cache_capacity),)
     out = jax.lax.while_loop(cond, body, state0)
-    g_best, s_best, rounds, steps = out[1], out[2], out[5], out[6]
+    res = (out[1][None], out[2][None], out[5])
     if use_cache:
-        cache = out[7]
-        hm = jnp.stack([cache.hits, cache.misses])[None]   # (1, 2) per device
-        return g_best[None], s_best[None], rounds, hm, steps[None]
-    return g_best[None], s_best[None], rounds, steps[None]
+        cache = out[8]
+        res += (jnp.stack([cache.hits, cache.misses])[None],)  # (1, 2)
+    if q_buckets:
+        res += (out[7][None],)
+    return res + (out[6][None],)
 
 
 def build_ring_program(mesh: Mesh, spec: RingSpec, config: GESConfig,
-                       r_max: int, add_limit: int, restricted: bool = False):
+                       r_max: int, add_limit: int, restricted: bool = False,
+                       q_buckets: bool = False):
     """Compile-ready cGES stage-2 program for an arbitrary mesh.
 
     The ring axis is ``spec.axis``; data/arities are replicated, edge masks
@@ -202,17 +210,21 @@ def build_ring_program(mesh: Mesh, spec: RingSpec, config: GESConfig,
     caller owns sentinel-padding ragged m (sweeps.pad_data_rows — ring_cges
     does it).  With ``config.family_cache`` the program returns a fourth
     (k, 2) int32 output: per-ring-process (hits, misses) cache counters.
-    The last output is always the (k, max_rounds, 2) int32 insertions and
-    deletions of each ring process in each round.
+    ``q_buckets=True`` (``ring_cges`` sets it) adds the (k, max_rounds,
+    n_buckets) int32 ``ges.q_buckets`` histograms of each ring process in
+    each round.  The last output is always the (k, max_rounds, 2) int32
+    insertions and deletions of each ring process in each round.
     """
     axis = spec.axis
 
     body = partial(_ring_body, spec=spec, config=config, r_max=r_max,
-                   add_limit=add_limit)
+                   add_limit=add_limit, q_buckets=q_buckets)
 
     data_spec = P() if spec.data_axis is None else P(spec.data_axis, None)
     pid_specs = (P(axis, None, None),) if restricted else ()
     stat_specs = (P(axis, None),) if config.family_cache else ()
+    if q_buckets:
+        stat_specs += (P(axis, None, None),)
     mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(data_spec, P(), P(axis, None, None), P(axis, None, None))
@@ -269,7 +281,7 @@ def ring_cges(
     # cache load) and enqueue, ring.run the blocking readback.
     with TraceAnnotation("ring.build"):
         prog = build_ring_program(mesh, spec, config, r_max, lim,
-                                  restricted=restricted)
+                                  restricted=restricted, q_buckets=True)
         data = np.asarray(data)
         if spec.data_axis is not None and spec.data_axis_size > 1:
             data = np.asarray(pad_data_rows(data.astype(np.int32), r_max,
@@ -290,11 +302,12 @@ def ring_cges(
     with TraceAnnotation("ring.run") as span:
         graphs, scores = np.asarray(out[0]), np.asarray(out[1])
         rounds = int(out[2])
-        steps = np.asarray(out[-1])
+        steps, q_buckets = np.asarray(out[-1]), np.asarray(out[-2])
         span.set_metadata(rounds=rounds)
     for r in range(rounds):
         for i in range(k):
             trace_steps(r, i, *steps[i, r])
+            trace_q_buckets(r, i, q_buckets[i, r], config.max_q)
     if return_cache_stats:
         if not config.family_cache:
             raise ValueError("return_cache_stats requires config.family_cache")

@@ -173,7 +173,8 @@ def _check_pids(pids, n: int, name: str = "pids") -> Array:
 # ---------------------------------------------------------------------------
 
 def sweep_column_body(data, arities, adj, y, pids, ess, max_q, r_max,
-                      counts_impl, kind, data_axis_name=None):
+                      counts_impl, kind, data_axis_name=None,
+                      bucket_rows=True):
     """Traceable masked delta column — callable from inside jit/shard_map.
 
     Returns (n,) deltas for toggling x -> y over all candidates x, or (W,)
@@ -182,6 +183,9 @@ def sweep_column_body(data, arities, adj, y, pids, ess, max_q, r_max,
     contraction (insert) or one family-table build (delete) instead of one
     table build per candidate.  With ``data_axis_name`` every count build
     contracts the local m/d shard and psums (module docstring: data axis).
+    ``bucket_rows``: the fused engines reduce only the bucket of table rows
+    that covers the child's parent configurations (bdeu._reduce_rows); pass
+    False where the column is batched under vmap.
     """
     insert = _check_kind(kind)
     n = adj.shape[0]
@@ -194,7 +198,8 @@ def sweep_column_body(data, arities, adj, y, pids, ess, max_q, r_max,
     if counts_impl in bdeu.FUSED_IMPLS:
         fn = bdeu.fused_insert_scores if insert else bdeu.fused_delete_scores
         deltas = fn(data, arities, y, pm, ess, max_q, r_max, counts_impl,
-                    pids=pids, data_axis_name=data_axis_name) - base
+                    pids=pids, data_axis_name=data_axis_name,
+                    bucket_rows=bucket_rows) - base
     elif insert:
         # The ONE loop-engine insert primitive (incremental config
         # encoding) — shared with bdeu._deltas_impl's full matrix, so a
@@ -286,35 +291,32 @@ def sweep_matrix_restricted_body(data, arities, adj, pid_table, ess, max_q,
     _check_kind(kind)
     n = adj.shape[0]
 
-    def per_child(args):
-        y, pids = args
-        return sweep_column_body(data, arities, adj, y, pids, ess, max_q,
-                                 r_max, counts_impl, kind,
-                                 data_axis_name=data_axis_name)
-
     if counts_impl in bdeu.FUSED_IMPLS and child_chunk is None:
         # Same memory bound as bdeu._deltas_impl: a fused child column
         # materializes an (r_max, r_max, max_q, W) count slab — map children
         # sequentially so one slab lives at a time.
         child_chunk = 1
 
-    def map_children(ids, rows):
-        cnt = ids.shape[0]
-        if child_chunk is None or child_chunk >= cnt:
-            return jax.vmap(per_child)((ids, rows))              # (cnt, W)
-        return jax.lax.map(per_child, (ids, rows),
-                           batch_size=min(child_chunk, cnt))
+    def per_child(args):
+        y, pids = args
+        return sweep_column_body(data, arities, adj, y, pids, ess, max_q,
+                                 r_max, counts_impl, kind,
+                                 data_axis_name=data_axis_name,
+                                 bucket_rows=child_chunk == 1)
 
     if axis_name is not None:
         per = -(-n // axis_size)                    # children per device
         i = jax.lax.axis_index(axis_name)
         ids = jnp.clip(i * per + jnp.arange(per), 0, n - 1).astype(jnp.int32)
-        cols_l = map_children(ids, jnp.take(pid_table, ids, axis=0))
+        cols_l = bdeu.map_children(
+            per_child, (ids, jnp.take(pid_table, ids, axis=0)),
+            child_chunk)                                         # (cnt, W)
         cols = jax.lax.all_gather(cols_l, axis_name, axis=0,
                                   tiled=True)[:n]                # (n, W)
         return cols.T
     children = jnp.arange(n, dtype=jnp.int32)
-    return map_children(children, pid_table).T                   # (W, n)
+    return bdeu.map_children(per_child, (children, pid_table),
+                             child_chunk).T                      # (W, n)
 
 
 @partial(jax.jit, static_argnames=("ess", "max_q", "r_max", "counts_impl",

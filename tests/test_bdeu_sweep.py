@@ -2,6 +2,7 @@
 per-candidate loop engine, overflow guard, restricted-subset columns, and
 end-to-end trajectory identity of ges_jit across counts_impls."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -182,3 +183,133 @@ def test_ges_host_trajectory_identity_across_impls(case):
                      config=GESConfig(max_q=256, counts_impl="fused"))
     assert np.array_equal(res_s.adj, res_f.adj)
     assert np.isclose(res_s.score, res_f.score, rtol=1e-9)
+
+
+# ---------------------------------------------------------------------------
+# Row buckets of the fused reduction (bdeu._reduce_rows)
+# ---------------------------------------------------------------------------
+
+# Per family: the arities of a 12-column dataset, the child, and the parent
+# sets giving each q0 on a side of an edge of q_ladder(1024) = (16, 64, 256,
+# 1024).  17, 65 and 257 are no product of these arities, so each edge's
+# upper side is the least q0 above it that is.
+Q_CASES = {
+    "pigs": ((3,) * 12, 11,
+             {1: [], 9: [0, 1], 27: [0, 1, 2], 81: [0, 1, 2, 3],
+              243: [0, 1, 2, 3, 4], 729: [0, 1, 2, 3, 4, 5],
+              2187: [0, 1, 2, 3, 4, 5, 6]}),
+    "link": ((4, 4, 4, 4, 4, 2, 3, 3, 2, 3, 4, 2), 10,
+             {1: [], 16: [0, 1], 18: [5, 6, 7], 64: [0, 1, 2],
+              72: [0, 5, 6, 7], 256: [0, 1, 2, 3], 288: [0, 1, 5, 6, 7],
+              1024: [0, 1, 2, 3, 4], 1152: [0, 1, 2, 5, 6, 7]}),
+}
+Q_MAX = 1024
+
+
+@pytest.fixture(scope="module")
+def q_data():
+    rng = np.random.default_rng(17)
+    out = {}
+    for fam, (ar, _, _) in Q_CASES.items():
+        data = np.stack([rng.integers(0, a, size=2000) for a in ar], 1)
+        out[fam] = (jnp.asarray(data.astype(np.int32)),
+                    jnp.asarray(np.asarray(ar, dtype=np.int32)))
+    return out
+
+
+def _fused_column(dj, aj, child, pm, impl, kind, bucket_rows):
+    fn = (bdeu.fused_insert_scores if kind == "insert"
+          else bdeu.fused_delete_scores)
+    return fn(dj, aj, child, pm, 10.0, Q_MAX, 4, impl,
+              bucket_rows=bucket_rows)
+
+
+_fused_column_jit = jax.jit(_fused_column,
+                            static_argnames=("impl", "kind", "bucket_rows"))
+
+
+@pytest.mark.parametrize("max_q, ladder", [
+    (8, (8,)), (63, (63,)), (64, (16, 64)), (1000, (62, 250, 1000)),
+    (1024, (16, 64, 256, 1024)), (4096, (16, 64, 256, 1024, 4096)),
+    (1 << 20, (4096, 16384, 65536, 262144, 1 << 20))])
+def test_q_ladder(max_q, ladder):
+    """Buckets of max_q / 4^k rows, at least 16, at most five; q_bucket
+    picks the least that holds q0, the last past max_q."""
+    assert bdeu.q_ladder(max_q) == ladder
+    q0 = np.array([1, *ladder, ladder[-1] + 1])
+    got = np.asarray(bdeu.q_bucket(jnp.asarray(q0, jnp.float32), max_q))
+    assert list(got) == [0, *range(len(ladder)), len(ladder) - 1]
+
+
+@pytest.mark.parametrize("kind", ["insert", "delete"])
+@pytest.mark.parametrize("impl", FUSED_IMPLS)
+@pytest.mark.parametrize("fam, q0", [(f, q) for f, c in Q_CASES.items()
+                                     for q in c[2]],
+                         ids=lambda v: str(v))
+def test_bucketed_reduction_matches_dense(q_data, fam, q0, impl, kind):
+    """A column reduced over its q0 bucket equals the dense max_q-row
+    reduction within float32 reassociation, with the same argmax over the
+    legal candidates and the same -inf pattern (q0 * r_x > max_q inserts,
+    overflowing families, q0 past max_q)."""
+    ar, child, parents = Q_CASES[fam]
+    assert int(np.prod([ar[p] for p in parents[q0]])) == q0
+    dj, aj = q_data[fam]
+    pm = np.zeros(len(ar), dtype=bool)
+    pm[parents[q0]] = True
+    got, dense = (np.asarray(_fused_column_jit(
+        dj, aj, jnp.int32(child), jnp.asarray(pm), impl=impl, kind=kind,
+        bucket_rows=b)) for b in (True, False))
+    assert np.array_equal(np.isneginf(got), np.isneginf(dense))
+    fin = np.isfinite(dense)
+    assert np.allclose(got[fin], dense[fin], rtol=1e-6, atol=0)
+    legal = ~pm if kind == "insert" else pm.copy()
+    legal[child] = False
+    if (legal & fin).any():
+        masked = lambda s: np.where(legal, s, -np.inf)
+        assert np.argmax(masked(got)) == np.argmax(masked(dense))
+    if kind == "insert" and q0 >= Q_MAX:
+        assert np.isneginf(got).all()           # past the table: stays -inf
+
+
+def _bucket_conds(jaxpr, max_q):
+    """Index avals of the ``cond`` equations with one branch per bucket."""
+    from repro.analysis.contracts import iter_eqns
+
+    n_buckets = len(bdeu.q_ladder(max_q))
+    return [e.invars[0].aval for e in iter_eqns(jaxpr)
+            if e.primitive.name == "cond"
+            and len(e.params["branches"]) == n_buckets]
+
+
+@pytest.mark.parametrize("restricted", [False, True],
+                         ids=["full", "restricted"])
+@pytest.mark.parametrize("kind", ["insert", "delete"])
+@pytest.mark.parametrize("impl", FUSED_IMPLS)
+def test_child_sweeps_switch_on_an_unbatched_bucket(case, impl, kind,
+                                                    restricted):
+    """The fused matrix sweeps map children one at a time without vmap, so
+    the row-bucket switch stays a ``cond`` on a scalar index: under vmap a
+    batched index becomes a select that runs every branch.  Children
+    batched wider than one (child_chunk >= n) reduce densely instead: no
+    bucket cond at all."""
+    from repro.core.partition import pid_table_from_allowed
+    from repro.core.sweeps import (sweep_matrix_body,
+                                   sweep_matrix_restricted_body)
+
+    bn, data = case
+    dj, aj = _jnp_inputs(bn, data)
+    n = bn.n
+    adj = jnp.zeros((n, n), jnp.int8).at[0, 2].set(1)
+    kw = dict(ess=10.0, max_q=Q_MAX, r_max=int(bn.arities.max()),
+              counts_impl=impl, kind=kind)
+    if restricted:
+        allowed = ~np.eye(n, dtype=bool)
+        tbl = jnp.asarray(pid_table_from_allowed(allowed))
+        body = lambda chunk: (lambda a: sweep_matrix_restricted_body(
+            dj, aj, a, tbl, child_chunk=chunk, **kw))
+    else:
+        body = lambda chunk: (lambda a: sweep_matrix_body(
+            dj, aj, a, child_chunk=chunk, **kw))
+    conds = _bucket_conds(jax.make_jaxpr(body(None))(adj), Q_MAX)
+    assert conds and all(aval.shape == () for aval in conds)
+    assert not _bucket_conds(jax.make_jaxpr(body(n))(adj), Q_MAX)
